@@ -108,17 +108,22 @@ def comm_bytes(n_rows: int, feat_dim: int, bits: int,
 
 
 def pack_bits(vals: jax.Array, bits: int) -> jax.Array:
-    """Pack uint8 values in [0, 2^bits-1] along the last axis, 8//bits per byte."""
+    """Pack uint8 values in [0, 2^bits-1] along the last axis, 8//bits per byte.
+
+    Strided lane groups: with ``w = packed_width(d, bits)``, byte ``j`` holds
+    values ``j + i*w`` at bit offset ``i*bits`` for ``i < 8//bits`` (zero past
+    ``d``) — the layout the Pallas kernel (``repro.kernels.quant``) emits.
+    """
     if bits == 8 or bits not in PACKABLE_BITS:
         return vals.astype(jnp.uint8)
     k = _lanes_per_byte(bits)
     d = vals.shape[-1]
-    pad = (-d) % k
-    if pad:
-        vals = jnp.pad(vals, [(0, 0)] * (vals.ndim - 1) + [(0, pad)])
-    grouped = vals.reshape(*vals.shape[:-1], -1, k).astype(jnp.uint8)
-    shifts = (jnp.arange(k, dtype=jnp.uint8) * np.uint8(bits)).astype(jnp.uint8)
-    return jnp.bitwise_or.reduce(grouped << shifts, axis=-1).astype(jnp.uint8)
+    w = packed_width(d, bits)
+    vals = jnp.pad(vals.astype(jnp.uint8),
+                   [(0, 0)] * (vals.ndim - 1) + [(0, k * w - d)])
+    grouped = vals.reshape(*vals.shape[:-1], k, w)
+    shifts = (jnp.arange(k, dtype=jnp.uint8) * np.uint8(bits))[:, None]
+    return jnp.bitwise_or.reduce(grouped << shifts, axis=-2).astype(jnp.uint8)
 
 
 def unpack_bits(packed: jax.Array, bits: int, feat_dim: int) -> jax.Array:
@@ -127,8 +132,8 @@ def unpack_bits(packed: jax.Array, bits: int, feat_dim: int) -> jax.Array:
         return packed[..., :feat_dim]
     k = _lanes_per_byte(bits)
     mask = np.uint8((1 << bits) - 1)
-    shifts = (jnp.arange(k, dtype=jnp.uint8) * np.uint8(bits)).astype(jnp.uint8)
-    vals = (packed[..., :, None] >> shifts) & mask
+    shifts = (jnp.arange(k, dtype=jnp.uint8) * np.uint8(bits))[:, None]
+    vals = (packed[..., None, :] >> shifts) & mask       # (..., k, w)
     return vals.reshape(*packed.shape[:-1], -1)[..., :feat_dim]
 
 

@@ -9,7 +9,7 @@ and places host arrays onto the mesh.
 
 Sharding contract (one partition per device):
   * model params / optimizer state / step counter — replicated (``P()``).
-    shard_map runs with replication checking OFF (see ``compat.shard_map``):
+    shard_map runs with replication checking OFF (``check_vma=False``):
     nothing reduces the replicated params' cotangents at the boundary, so the
     step functions all-reduce weight gradients with an explicit
     ``backend.psum`` (Alg. 2 line 16) — do not remove that psum.
@@ -29,9 +29,9 @@ from __future__ import annotations
 from typing import Any
 
 import jax
+from jax.sharding import AxisType
 from jax.sharding import PartitionSpec as P
 
-from . import compat
 from .backend import (HaloBackend, ShardMapBackend, SimulatedBackend,  # noqa: F401
                       as_backend)
 
@@ -57,7 +57,7 @@ def make_gnn_mesh(n_parts: int | None = None, axis_name: str = "parts"):
     """A 1-D ``(n_parts,)`` mesh — the canonical GNN topology (one partition
     per device). Defaults to every visible device."""
     n = n_parts if n_parts is not None else len(jax.devices())
-    return compat.make_mesh((n,), (axis_name,))
+    return jax.make_mesh((n,), (axis_name,), axis_types=(AxisType.Auto,))
 
 
 # ---------------------------------------------------------------------------

@@ -47,8 +47,6 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from . import compat
-
 if TYPE_CHECKING:  # import-cycle guard: see _exchange_quantized
     from ..core.quantization import QuantizedTensor
 
@@ -184,8 +182,8 @@ class SimulatedBackend:
 @partial(jax.custom_vjp, nondiff_argnums=(1,))
 def _rep_psum(x, axes):
     """All-reduce whose output is *replicated*: the cotangent of a replicated
-    value is itself replicated, so the transpose is the identity (what modern
-    check_vma replication tracking infers; under ``check_rep=False`` the raw
+    value is itself replicated, so the transpose is the identity (what
+    check_vma replication tracking infers; under ``check_vma=False`` the raw
     ``lax.psum`` would transpose to another psum and over-count by P)."""
     return jax.lax.psum(x, axes)
 
@@ -262,7 +260,7 @@ class ShardMapBackend:
         names = self.axis_names
         idx = jax.lax.axis_index(names[0])
         for a in names[1:]:
-            idx = idx * compat.axis_size(a) + jax.lax.axis_index(a)
+            idx = idx * jax.lax.axis_size(a) + jax.lax.axis_index(a)
         return idx
 
     def _require_mesh(self, what: str):
@@ -280,8 +278,8 @@ class ShardMapBackend:
         # custom_vjp exchanges, so the steps reduce weight gradients with an
         # explicit self.psum (Alg. 2 line 16) instead of a boundary check.
         self._require_mesh("shard")
-        return jax.jit(compat.shard_map(fn, self.mesh, in_specs=in_specs,
-                                        out_specs=out_specs, check=False))
+        return jax.jit(jax.shard_map(fn, mesh=self.mesh, in_specs=in_specs,
+                                     out_specs=out_specs, check_vma=False))
 
 
 def as_backend(b: Any) -> HaloBackend:

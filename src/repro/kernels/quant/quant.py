@@ -12,10 +12,19 @@ HBM-bandwidth-bound, so one pass is the roofline).
 
 Tiling: grid over row blocks. Each invocation holds a ``(block_rows, d)`` tile
 of the send buffer plus the same-shape uniform-noise tile in VMEM, and emits a
-``(block_rows, d // lanes)`` uint8 tile plus per-row ``(scale, zero)``. ``d`` is
-the feature width of one GNN layer (32-1433 here) so a tile is <= a few hundred
-KB — far under the ~16 MB VMEM budget; ``block_rows`` defaults to 256 rows to
-keep the sublane dimension busy.
+``(block_rows, w)`` uint8 tile, ``w = ceil(d / lanes)``, plus per-row
+``(scale, zero)`` as ``(block_rows, 1)`` columns. ``d`` is the feature width of
+one GNN layer (32-1433 here) so a tile is <= a few hundred KB — far under the
+~16 MB VMEM budget; ``block_rows`` defaults to 256 rows to keep the sublane
+dimension busy.
+
+Packing uses strided lane groups: byte ``j`` holds values ``j, j + w, j + 2w,
+...`` at bit offsets ``0, bits, 2 bits, ...``. Group ``i`` is the lane slice
+``[i w, (i + 1) w)`` of the tile, so packing is shifts and ORs of lane slices
+and unpacking is shifts and masks stored to lane slices. Mosaic lowers these;
+it does not lower the lane-splitting reshape a contiguous layout needs
+(``(br, d) -> (br, w, lanes)``), nor uint8 <-> float casts, which go through
+int32 here.
 
 Stochastic-rounding noise is passed in as a uniform tensor generated with
 ``jax.random.uniform`` outside the kernel (counter-based, reproducible across
@@ -47,30 +56,38 @@ def _quantize_kernel(h_ref, u_ref, packed_ref, scale_ref, zero_ref, *,
     hbar = (h - lo) / safe * big
     floor = jnp.floor(hbar)
     q = floor + (u < (hbar - floor)).astype(jnp.float32)
-    q = jnp.clip(q, 0.0, big).astype(jnp.uint8)
+    q = jnp.clip(q, 0.0, big).astype(jnp.int32)
 
-    k = 8 // bits
-    pad = (-d) % k
-    if pad:
-        q = jnp.pad(q, ((0, 0), (0, pad)))
-    grouped = q.reshape(q.shape[0], -1, k)          # (br, w, k)
-    shifts = (jnp.arange(k, dtype=jnp.uint8) * np.uint8(bits)).astype(jnp.uint8)
-    shifted = grouped << shifts                     # or-reduce over lane group
-    packed_ref[...] = jax.lax.reduce(
-        shifted, np.uint8(0), jax.lax.bitwise_or, dimensions=(2,))
-    scale_ref[...] = (rng[:, 0] / big).astype(jnp.float32)
-    zero_ref[...] = lo[:, 0].astype(jnp.float32)
+    # strided lane groups: byte j holds values j, j + w, j + 2w, ... (the
+    # layout of core.quantization.pack_bits); lane slices only, no reshape
+    w = packed_ref.shape[-1]
+    packed = q[:, :w]
+    for i in range(1, 8 // bits):
+        n = min(w, d - i * w)                       # lanes of group i
+        if n <= 0:
+            break
+        seg = q[:, i * w:i * w + n]
+        if n < w:
+            seg = jnp.concatenate(
+                [seg, jnp.zeros((seg.shape[0], w - n), jnp.int32)], axis=-1)
+        packed = packed | (seg << (i * bits))
+    packed_ref[...] = packed.astype(jnp.uint8)
+    scale_ref[...] = rng / big
+    zero_ref[...] = lo
 
 
 def _dequantize_kernel(packed_ref, scale_ref, zero_ref, out_ref, *,
                        bits: int, d: int):
-    packed = packed_ref[...]                        # (br, w) uint8
-    k = 8 // bits
-    mask = np.uint8((1 << bits) - 1)
-    shifts = (jnp.arange(k, dtype=jnp.uint8) * np.uint8(bits)).astype(jnp.uint8)
-    vals = (packed[:, :, None] >> shifts) & mask    # (br, w, k)
-    vals = vals.reshape(packed.shape[0], -1)[:, :d].astype(jnp.float32)
-    out_ref[...] = vals * scale_ref[...][:, None] + zero_ref[...][:, None]
+    packed = packed_ref[...].astype(jnp.int32)      # (br, w)
+    w = packed.shape[-1]
+    mask = (1 << bits) - 1
+    scale, zero = scale_ref[...], zero_ref[...]     # (br, 1)
+    for i in range(8 // bits):
+        n = min(w, d - i * w)                       # lanes of group i
+        if n <= 0:
+            break
+        vals = ((packed >> (i * bits)) & mask)[:, :n].astype(jnp.float32)
+        out_ref[:, i * w:i * w + n] = vals * scale + zero
 
 
 def _grid(rows: int, block_rows: int) -> tuple[int, int]:
@@ -91,8 +108,8 @@ def quantize_pack(h: jax.Array, u: jax.Array, bits: int = 1,
     w = (d + (8 // bits) - 1) // (8 // bits)
     out_shapes = (
         jax.ShapeDtypeStruct((n_blocks * br, w), jnp.uint8),
-        jax.ShapeDtypeStruct((n_blocks * br,), jnp.float32),
-        jax.ShapeDtypeStruct((n_blocks * br,), jnp.float32),
+        jax.ShapeDtypeStruct((n_blocks * br, 1), jnp.float32),
+        jax.ShapeDtypeStruct((n_blocks * br, 1), jnp.float32),
     )
     packed, scale, zero = pl.pallas_call(
         functools.partial(_quantize_kernel, bits=bits, d=d),
@@ -100,12 +117,12 @@ def quantize_pack(h: jax.Array, u: jax.Array, bits: int = 1,
         in_specs=[pl.BlockSpec((br, d), lambda i: (i, 0)),
                   pl.BlockSpec((br, d), lambda i: (i, 0))],
         out_specs=(pl.BlockSpec((br, w), lambda i: (i, 0)),
-                   pl.BlockSpec((br,), lambda i: (i,)),
-                   pl.BlockSpec((br,), lambda i: (i,))),
+                   pl.BlockSpec((br, 1), lambda i: (i, 0)),
+                   pl.BlockSpec((br, 1), lambda i: (i, 0))),
         out_shape=out_shapes,
         interpret=interpret,
     )(h, u)
-    return packed[:rows], scale[:rows], zero[:rows]
+    return packed[:rows], scale[:rows, 0], zero[:rows, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "d", "block_rows", "interpret"))
@@ -116,16 +133,17 @@ def unpack_dequantize(packed: jax.Array, scale: jax.Array, zero: jax.Array,
     rows, w = packed.shape
     n_blocks, br = _grid(rows, block_rows)
     pad = n_blocks * br - rows
+    scale, zero = scale[:, None], zero[:, None]
     if pad:
         packed = jnp.pad(packed, ((0, pad), (0, 0)))
-        scale = jnp.pad(scale, (0, pad))
-        zero = jnp.pad(zero, (0, pad))
+        scale = jnp.pad(scale, ((0, pad), (0, 0)))
+        zero = jnp.pad(zero, ((0, pad), (0, 0)))
     out = pl.pallas_call(
         functools.partial(_dequantize_kernel, bits=bits, d=d),
         grid=(n_blocks,),
         in_specs=[pl.BlockSpec((br, w), lambda i: (i, 0)),
-                  pl.BlockSpec((br,), lambda i: (i,)),
-                  pl.BlockSpec((br,), lambda i: (i,))],
+                  pl.BlockSpec((br, 1), lambda i: (i, 0)),
+                  pl.BlockSpec((br, 1), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n_blocks * br, d), jnp.float32),
         interpret=interpret,

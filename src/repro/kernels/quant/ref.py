@@ -9,24 +9,15 @@ from __future__ import annotations
 import jax.numpy as jnp
 import numpy as np
 
-
-def lanes_per_byte(bits: int) -> int:
-    assert bits in (1, 2, 4, 8)
-    return 8 // bits
-
-
-def packed_width(d: int, bits: int) -> int:
-    k = lanes_per_byte(bits)
-    return (d + k - 1) // k
+from ...core.quantization import pack_bits, unpack_bits
 
 
 def quantize_pack_ref(h: jnp.ndarray, u: jnp.ndarray, bits: int):
     """(rows, d) float32, (rows, d) uniform[0,1) -> (packed uint8, scale, zero).
 
     Per-row affine quantization (paper Equ. 3) with stochastic rounding (Equ. 4),
-    packed 8//bits lanes per byte little-endian within the byte.
+    packed 8//bits lanes per byte in strided lane groups (``pack_bits``).
     """
-    rows, d = h.shape
     big = np.float32(2.0**bits - 1.0)
     lo = jnp.min(h, axis=-1, keepdims=True)
     hi = jnp.max(h, axis=-1, keepdims=True)
@@ -37,13 +28,7 @@ def quantize_pack_ref(h: jnp.ndarray, u: jnp.ndarray, bits: int):
     q = floor + (u < (hbar - floor)).astype(jnp.float32)
     q = jnp.clip(q, 0.0, big).astype(jnp.uint8)
 
-    k = lanes_per_byte(bits)
-    pad = (-d) % k
-    if pad:
-        q = jnp.pad(q, ((0, 0), (0, pad)))
-    grouped = q.reshape(rows, -1, k)
-    shifts = (jnp.arange(k, dtype=jnp.uint8) * np.uint8(bits)).astype(jnp.uint8)
-    packed = jnp.bitwise_or.reduce(grouped << shifts, axis=-1).astype(jnp.uint8)
+    packed = pack_bits(q, bits)
     scale = (rng[:, 0] / big).astype(jnp.float32)
     zero = lo[:, 0].astype(jnp.float32)
     return packed, scale, zero
@@ -52,9 +37,5 @@ def quantize_pack_ref(h: jnp.ndarray, u: jnp.ndarray, bits: int):
 def unpack_dequantize_ref(packed: jnp.ndarray, scale: jnp.ndarray,
                           zero: jnp.ndarray, bits: int, d: int) -> jnp.ndarray:
     """(rows, packed_width) uint8 + per-row (scale, zero) -> (rows, d) float32."""
-    k = lanes_per_byte(bits)
-    mask = np.uint8((1 << bits) - 1)
-    shifts = (jnp.arange(k, dtype=jnp.uint8) * np.uint8(bits)).astype(jnp.uint8)
-    vals = (packed[:, :, None] >> shifts) & mask
-    vals = vals.reshape(packed.shape[0], -1)[:, :d].astype(jnp.float32)
+    vals = unpack_bits(packed, bits, d).astype(jnp.float32)
     return vals * scale[:, None] + zero[:, None]

@@ -24,7 +24,6 @@ from ..configs.base import ArchSpec, ShapeCell
 from ..core.staleness import HaloState
 from ..core.sylvie import SylvieConfig
 from ..dist import api as dist
-from ..dist import compat
 from ..graph.partition import analytic_partition_spec
 from ..graph.sampling import SamplerShapes
 from ..models.gnn import blocks as B
@@ -55,7 +54,7 @@ class Cell:
         if self.shard_ctx is not None:
             LM.set_shard_ctx(self.shard_ctx)
             try:
-                with compat.use_mesh(self.mesh):
+                with jax.set_mesh(self.mesh):
                     return self.fn.lower(*self.args)
             finally:
                 LM.set_shard_ctx(None)
@@ -329,18 +328,19 @@ def _dlrm_cell(spec: ArchSpec, cell: ShapeCell, mesh, *,
         ids = jax.ShapeDtypeStruct((b * cfg.total_ids_per_sample,), jnp.int32)
         lb = jax.ShapeDtypeStruct((b,), jnp.float32)
         step = D.make_train_step(cfg, opt, axes)
-        fn = jax.jit(compat.shard_map(
-            step, mesh,
+        fn = jax.jit(jax.shard_map(
+            step, mesh=mesh,
             in_specs=((rep, shard, rep, tspec, rep), shard, shard, shard, rep),
-            out_specs=((rep, shard, rep, tspec, rep), rep)))
+            out_specs=((rep, shard, rep, tspec, rep), rep), check_vma=False))
         args = (state, dx, ids, lb, KEY_SDS)
     elif cell.step == "serve":
         b = cell.params["batch"]
         dx = jax.ShapeDtypeStruct((b, cfg.n_dense), jnp.float32)
         ids = jax.ShapeDtypeStruct((b * cfg.total_ids_per_sample,), jnp.int32)
-        fn = jax.jit(compat.shard_map(
-            D.make_serve_step(cfg, axes), mesh,
-            in_specs=(rep, shard, shard, shard), out_specs=shard))
+        fn = jax.jit(jax.shard_map(
+            D.make_serve_step(cfg, axes), mesh=mesh,
+            in_specs=(rep, shard, shard, shard), out_specs=shard,
+            check_vma=False))
         args = (dense, table, dx, ids)
     else:  # retrieval
         ncand = cell.params["n_candidates"]
@@ -348,9 +348,10 @@ def _dlrm_cell(spec: ArchSpec, cell: ShapeCell, mesh, *,
         dx = jax.ShapeDtypeStruct((1, cfg.n_dense), jnp.float32)
         ids = jax.ShapeDtypeStruct((cfg.total_ids_per_sample,), jnp.int32)
         cand = jax.ShapeDtypeStruct((ncand,), jnp.int32)
-        fn = jax.jit(compat.shard_map(
-            D.make_retrieval_step(cfg, axes), mesh,
-            in_specs=(rep, shard, rep, rep, shard), out_specs=(rep, rep)))
+        fn = jax.jit(jax.shard_map(
+            D.make_retrieval_step(cfg, axes), mesh=mesh,
+            in_specs=(rep, shard, rep, rep, shard), out_specs=(rep, rep),
+            check_vma=False))
         args = (dense, table, dx, ids, cand)
 
     return Cell(spec.arch_id, cell.name, cell.step, fn, args, p_n,

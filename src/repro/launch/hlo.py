@@ -194,8 +194,7 @@ class Roofline:
 def analyze(compiled, n_devices: int,
             model_flops_total: Optional[float] = None):
     """(compiled executable, mesh size) -> (Roofline, CollectiveStats, mem)."""
-    from ..dist import compat
-    cost = compat.cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     flops = float(cost.get("flops", 0.0))
     hbm = float(cost.get("bytes accessed", 0.0))
     stats = collective_bytes(compiled.as_text(), n_devices)

@@ -13,18 +13,25 @@ partitions); the LM runtime uses FSDP over ("pod","data") and TP/EP over
 """
 from __future__ import annotations
 
-from ..dist import compat
+import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_test_mesh(shape=(2, 2), axes=("data", "model")):
     """Small mesh for CPU multi-device tests (device count forced by caller)."""
-    return compat.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
+
+
+def _auto_mesh(shape, axes):
+    # Auto axes: shardings are propagated by the compiler (jax.make_mesh
+    # defaults to Explicit axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def flat_axes(mesh) -> tuple[str, ...]:

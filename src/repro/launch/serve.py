@@ -343,6 +343,8 @@ def main() -> None:
     ap.add_argument("--out", default=None, help="report JSON path override")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    from .cache import use_compile_cache
+    use_compile_cache()
 
     if args.matrix:
         run_serve_matrix(args.matrix)

@@ -25,6 +25,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .cache import use_compile_cache
+
 
 def build_policy(args):
     """CLI -> CommPolicy. ``--eps-s`` maps onto the BoundedStaleness policy
@@ -47,34 +49,45 @@ def build_policy(args):
     return None  # Uniform from the SylvieConfig
 
 
-def train_gnn(args) -> None:
-    from .. import configs as configlib
-    from ..core.sylvie import SylvieConfig
+def build_gnn_trainer(arch, graph: str, parts: int, cfg, *, policy=None,
+                      seed: int = 0, runtime=None, ckpt_dir=None):
+    """Generate ``graph`` (a named workload ref or raw generator name) from
+    ``seed``, partition it ``parts`` ways and wrap ``arch`` in a
+    :class:`~repro.train.trainer.GNNTrainer` (simulated runtime by default)."""
     from ..graph import formats, partition, synthetic
     from ..models.gnn import blocks as B
     from ..train.trainer import GNNTrainer
 
     from .. import datasets
 
-    spec = configlib.get(args.arch)
-    arch = spec.reduced() if args.reduced else spec.config()
-    if args.graph in synthetic.GENERATORS:     # raw generator, default kwargs
-        g = synthetic.by_name(args.graph, seed=args.seed)
+    if graph in synthetic.GENERATORS:          # raw generator, default kwargs
+        g = synthetic.by_name(graph, seed=seed)
     else:                              # named workload ("reddit_like@small");
         # a typo raises the registry's KeyError listing the known names/tiers
-        g = datasets.load(args.graph, seed=args.seed)
+        g = datasets.load(graph, seed=seed)
     g, ew = formats.gcn_normalize(g)
     if arch.d_edge_attr:
         if g.pos is None:
             rng = np.random.default_rng(0)
             g.pos = rng.normal(0, 1, (g.n_nodes, 3)).astype(np.float32)
         g.edge_attr = B.geometry_edge_attr(g)
-    pg = partition.partition_graph(g, args.parts, edge_weight=ew)
+    pg = partition.partition_graph(g, parts, edge_weight=ew)
     model = arch.make(g.x.shape[1], g.n_classes)
+    return GNNTrainer(model, pg, cfg, policy=policy, seed=seed,
+                      runtime=runtime, ckpt_dir=ckpt_dir)
+
+
+def train_gnn(args) -> None:
+    from .. import configs as configlib
+    from ..core.sylvie import SylvieConfig
+
+    spec = configlib.get(args.arch)
+    arch = spec.reduced() if args.reduced else spec.config()
     cfg = SylvieConfig(mode=args.mode, bits=args.bits,
                        schedule=args.schedule or "blocking")
-    tr = GNNTrainer(model, pg, cfg, policy=build_policy(args), seed=args.seed,
-                    ckpt_dir=args.ckpt_dir)
+    tr = build_gnn_trainer(arch, args.graph, args.parts, cfg,
+                           policy=build_policy(args), seed=args.seed,
+                           ckpt_dir=args.ckpt_dir)
     if args.resume and tr.resume():
         print(f"resumed at epoch {tr.epoch}")
     t0 = time.time()
@@ -228,6 +241,7 @@ def main() -> None:
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    use_compile_cache()
 
     if args.scenario:
         from .scenarios import run_scenario

@@ -37,7 +37,6 @@ import numpy as np
 
 from ..nn import mlp, mlp_init
 from ...core import quantization as qlib
-from ...dist import compat
 
 # MLPerf DLRM (Criteo Terabyte) per-field vocabulary sizes.
 CRITEO_TABLE_SIZES = (
@@ -118,7 +117,7 @@ def _axis_index(axis_name):
     names = (axis_name,) if isinstance(axis_name, str) else tuple(axis_name)
     idx = jax.lax.axis_index(names[0])
     for a in names[1:]:
-        idx = idx * compat.axis_size(a) + jax.lax.axis_index(a)
+        idx = idx * jax.lax.axis_size(a) + jax.lax.axis_index(a)
     return idx
 
 
@@ -248,7 +247,7 @@ def make_train_step(cfg: DLRMConfig, optimizer, axis_name=None):
     The loss is sum-form normalized by the *global* batch, so per-device
     gradients are exact global-mean contributions; the replicated dense
     params' gradients are explicitly psummed (shard_map runs with replication
-    checking off — see repro.dist.compat.shard_map), and the table grads stay
+    checking off — see ShardMapBackend.shard), and the table grads stay
     local — each device owns its rows (the embedding collective's backward
     routes contributions to owners)."""
     def train_step(state, dense_x, flat_ids, labels, key):
@@ -258,7 +257,7 @@ def make_train_step(cfg: DLRMConfig, optimizer, axis_name=None):
             names = ((axis_name,) if isinstance(axis_name, str)
                      else tuple(axis_name))
             for a in names:
-                n_dev *= compat.axis_size(a)
+                n_dev *= jax.lax.axis_size(a)
 
         def loss_fn(dp, tb):
             logits = dlrm_forward(dp, tb, dense_x, flat_ids, cfg, axis_name,

@@ -45,11 +45,10 @@ class EFState:
 
 
 def _axis_size(axis_name) -> int:
-    from ..dist import compat
     names = (axis_name,) if isinstance(axis_name, str) else tuple(axis_name)
     n = 1
     for a in names:
-        n *= compat.axis_size(a)
+        n *= jax.lax.axis_size(a)
     return n
 
 

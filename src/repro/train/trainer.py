@@ -398,6 +398,14 @@ class GNNTrainer:
         self.epoch += 1
         return m
 
+    def compiled_step_text(self, sync: bool) -> str:
+        """Optimized HLO of the sync or async train step under the last
+        epoch's decision — shows which kernels and collectives it runs."""
+        ts, ta = self._steps_for(self._last_decision or self._decide())
+        return (ts if sync else ta).lower(
+            self.state, self.block, self.x, self.y, self.train_mask,
+            self._epoch_key()).compile().as_text()
+
     def evaluate(self, split: str = "val") -> float:
         mask = {"train": self.train_mask, "val": self.val_mask,
                 "test": self.test_mask}[split]

@@ -52,8 +52,8 @@ st_sim = GNNTrainState.create(model, o, key, block.plan, stacked_parts=P_)
 st_sim, _ = jax.jit(ts_sim)(st_sim, block, x, y, m, key)
 st_sim, loss_sim = jax.jit(ta_sim)(st_sim, block, x, y, m, key)
 
-from repro.dist import compat
-mesh = compat.make_mesh((4, 2), ("data", "model"))
+from repro.launch.mesh import make_test_mesh
+mesh = make_test_mesh((4, 2), ("data", "model"))
 ts_sm, ta_sm, ev_sm = make_gnn_steps(model, cfg, o,
                                      backend=dist.ShardMapBackend(mesh))
 st = GNNTrainState.create(model, o, key, block.plan, stacked_parts=P_)
@@ -93,15 +93,15 @@ step1 = jax.jit(D.make_train_step(cfg, o, None))
 st = (dp, tb1, o.init(dp), o.init(tb1), jnp.zeros((), jnp.int32))
 for i in range(8):
     st, loss1 = step1(st, dx, jnp.asarray(ids), labels, key)
-from repro.dist import compat
-mesh = compat.make_mesh((4, 2), ("data", "model"))
+from repro.launch.mesh import make_test_mesh
+mesh = make_test_mesh((4, 2), ("data", "model"))
 ax = ("data", "model")
 rpd = D.rows_per_device(cfg, 8)
 tb8 = jnp.pad(tb1, ((0, rpd*8 - tb1.shape[0]), (0, 0)))
 shard = P(ax); rep = P()
-sm = jax.jit(compat.shard_map(D.make_train_step(cfg, o, ax), mesh,
+sm = jax.jit(jax.shard_map(D.make_train_step(cfg, o, ax), mesh=mesh,
     in_specs=((rep, shard, rep, (), rep), shard, shard, shard, rep),
-    out_specs=((rep, shard, rep, (), rep), rep)))
+    out_specs=((rep, shard, rep, (), rep), rep), check_vma=False))
 st8 = (dp, tb8, o.init(dp), o.init(tb8), jnp.zeros((), jnp.int32))
 for i in range(8):
     st8, loss8 = sm(st8, dx, jnp.asarray(ids), labels, key)
@@ -112,9 +112,9 @@ np.testing.assert_allclose(np.asarray(st[1])[:cfg.total_rows],
 cfgq = D.DLRMConfig(n_dense=13, embed_dim=16, table_sizes=(50, 30, 20, 40),
                     bot_mlp=(32, 16), top_mlp=(64, 32, 1), hot=(2, 1, 1, 3),
                     quantize_collective_bits=8)
-smq = jax.jit(compat.shard_map(D.make_train_step(cfgq, o, ax), mesh,
+smq = jax.jit(jax.shard_map(D.make_train_step(cfgq, o, ax), mesh=mesh,
     in_specs=((rep, shard, rep, (), rep), shard, shard, shard, rep),
-    out_specs=((rep, shard, rep, (), rep), rep)))
+    out_specs=((rep, shard, rep, (), rep), rep), check_vma=False))
 stq = (dp, tb8, o.init(dp), o.init(tb8), jnp.zeros((), jnp.int32))
 for i in range(8):
     stq, lossq = smq(stq, dx, jnp.asarray(ids), labels,
@@ -142,14 +142,14 @@ state = (params, o.init(params), jnp.zeros((), jnp.int32))
 ts = jax.jit(LM.make_train_step(cfg, o))
 state1, loss1 = ts(state, tokens, labels)
 
-from repro.dist import compat
-mesh = compat.make_mesh((2, 2), ("data", "model"))
+from repro.launch.mesh import make_test_mesh
+mesh = make_test_mesh((2, 2), ("data", "model"))
 p_specs = lm_sharding.param_specs(params, cfg, mesh)
 pp = jax.device_put(params, jax.tree.map(lambda s: NamedSharding(mesh, s),
                                          p_specs))
 state_d = (pp, o.init(pp), jnp.zeros((), jnp.int32))
 LM.set_shard_ctx(LM.shard_ctx_from_mesh(mesh))
-with compat.use_mesh(mesh):
+with jax.set_mesh(mesh):
     ts_d = jax.jit(LM.make_train_step(cfg, o))
     state2, loss2 = ts_d(state_d, tokens, labels)
 LM.set_shard_ctx(None)

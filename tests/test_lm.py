@@ -122,9 +122,8 @@ def test_moe_flops_scale_with_topk_not_experts():
     def flops(m):
         p = LM.ffn_params(jax.random.fold_in(KEY, m.n_experts),
                           _tiny(), LayerConfig(AttnConfig(), moe=m), jnp.float32)
-        from repro.dist import compat
         c = jax.jit(lambda xx: LM.moe_ffn(p, xx, m)[0]).lower(x).compile()
-        return compat.cost_analysis(c).get("flops", 0.0)
+        return c.cost_analysis().get("flops", 0.0)
     f8, f32 = flops(m8), flops(m32)
     # 4x experts at fixed top-k: expert GEMM flops stay ~constant (capacity
     # shrinks as 1/E); total must grow far less than 4x
