@@ -434,9 +434,10 @@ def contract_obs_transparency() -> tuple[list[Finding], list[str]]:
     metrics counters live at the host seams (the same trace-time seams as the
     TRACE_LOG appends); enabling tracing must not add, drop, or reorder a
     single eqn. Checked by canon-comparing (hex addresses stripped) the
-    jaxprs of the sync + async train steps (``schedule="overlap"``, the one
-    path whose traced bodies *contain* obs.event seams) and the serve sweep,
-    traced with the tracer disabled vs enabled on a FakeClock."""
+    jaxprs of the sync + async train steps (``schedule="overlap"``; their
+    traced bodies hold the TRACE_LOG seams, which emit ``retrace`` events)
+    and the serve sweep, traced with the tracer disabled vs enabled on a
+    FakeClock."""
     import re
 
     from .. import obs

@@ -15,6 +15,10 @@ Two buffer layouts exist (see ``graph/partition.py``):
   backward communication (Alg. 2) calls :func:`exchange_halo` with
   ``reverse=True``. The layout is carried statically on :class:`PlanArrays`
   (``bucket_sizes``), so one code path in ``core/sylvie.py`` serves both.
+
+The boundary gather, the gradient scatter and the exchange entry points run
+under ``jax.named_scope("exchange")``, which the compiled program keeps in its
+``op_name`` metadata (the simulated roll and the shard_map collectives alike).
 """
 from __future__ import annotations
 
@@ -26,6 +30,7 @@ import jax.numpy as jnp
 
 from ..dist.backend import as_backend
 from .quantization import QuantizedTensor
+from .scopes import scoped
 
 
 @jax.tree_util.register_dataclass
@@ -85,6 +90,7 @@ class PlanArrays:
             bucket_sizes=None, wire_rows=wire, real_rows=wire)
 
 
+@scoped("exchange")
 def gather_boundary(h: jax.Array, plan: PlanArrays) -> jax.Array:
     """(P, n_local, d) -> (P, rows, d) packed send buffer (masked).
 
@@ -94,6 +100,7 @@ def gather_boundary(h: jax.Array, plan: PlanArrays) -> jax.Array:
     return jnp.where(plan.send_mask[..., None], buf, 0)
 
 
+@scoped("exchange")
 def scatter_boundary_grad(g: jax.Array, plan: PlanArrays) -> jax.Array:
     """(P, rows, d) received grads -> (P, n_local, d) scatter-add onto owners.
 
@@ -107,6 +114,7 @@ def scatter_boundary_grad(g: jax.Array, plan: PlanArrays) -> jax.Array:
     return jax.vmap(one)(g, plan.send_idx)
 
 
+@scoped("exchange")
 def exchange(x: jax.Array, backend=None) -> jax.Array:
     """The dense halo all-to-all. ``x``: (P_local, P*h_pad, ...) pairwise-blocked
     buffer.
@@ -118,12 +126,14 @@ def exchange(x: jax.Array, backend=None) -> jax.Array:
     return as_backend(backend).exchange(x)
 
 
+@scoped("exchange")
 def exchange_quantized(qt: QuantizedTensor, backend=None) -> QuantizedTensor:
     """Exchange a dense quantized payload: data + error-compensation (scale,
     zero) move together (paper §3.2 Communicator)."""
     return as_backend(backend).exchange_quantized(qt)
 
 
+@scoped("exchange")
 def exchange_halo(x: jax.Array, plan: PlanArrays, backend=None,
                   reverse: bool = False) -> jax.Array:
     """Layout-dispatching halo exchange. Dense plans use the transpose
@@ -135,6 +145,7 @@ def exchange_halo(x: jax.Array, plan: PlanArrays, backend=None,
     return be.exchange_compact(x, plan.bucket_sizes, reverse=reverse)
 
 
+@scoped("exchange")
 def exchange_quantized_halo(qt: QuantizedTensor, plan: PlanArrays, backend=None,
                             reverse: bool = False) -> QuantizedTensor:
     """Layout-dispatching quantized exchange (payload + scale/zero together)."""

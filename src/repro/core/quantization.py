@@ -33,6 +33,10 @@ take an ``impl`` designator —
 Both paths draw the same ``jax.random.uniform(key, h.shape)`` noise, so they are
 bit-identical in interpret mode. Cases the kernel does not cover (passthrough or
 odd bit-widths, deterministic rounding, scalar rows) silently fall back to jnp.
+
+:func:`quantize` and :func:`dequantize` run under ``jax.named_scope("lowbit")``
+on both paths: the noise draw, the pack and unpack, the kernels and the casts of
+scale and zero all carry it in the compiled program's ``op_name`` metadata.
 """
 from __future__ import annotations
 
@@ -43,6 +47,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from .scopes import scoped
 
 PACKABLE_BITS = (1, 2, 4)
 PASSTHROUGH_BITS = (16, 32)
@@ -182,6 +188,7 @@ def _dequantize_pallas(qt: QuantizedTensor, out_dtype) -> jax.Array:
     return out.reshape(lead + (qt.feat_dim,)).astype(out_dtype)
 
 
+@scoped("lowbit")
 def quantize(h: jax.Array, bits: int, key: Optional[jax.Array] = None,
              stochastic: bool = True,
              scale_dtype: jnp.dtype = jnp.bfloat16,
@@ -226,6 +233,7 @@ def quantize(h: jax.Array, bits: int, key: Optional[jax.Array] = None,
     return QuantizedTensor(packed, scale, zero, bits, d)
 
 
+@scoped("lowbit")
 def dequantize(qt: QuantizedTensor, out_dtype: jnp.dtype = jnp.float32,
                impl: Optional[str] = None) -> jax.Array:
     """Recover full-precision values per Equ. 5 (unbiased given Equ. 4)."""

@@ -50,7 +50,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from .. import obs
 from ..core import quantization as qlib
 from ..core.exchange import (PlanArrays, exchange_bytes,
                              exchange_quantized_halo, gather_boundary,
@@ -70,11 +69,7 @@ def fence(backend, tree):
 def _issue(buf, key, bits, stochastic, scale_dtype, backend, plan,
            reverse=False, impl="auto"):
     """Issue one direction's quantized exchange (same ops as the blocking
-    ``_q_roundtrip`` up to the collective — identical census). The obs event
-    fires at trace time (this body only runs when jit traces) — it marks a
-    *compiled* issue site, same seam as the TRACE_LOG appends, and emits no
-    traced op (RC210)."""
-    obs.event("halo.issue", {"bits": int(bits), "reverse": bool(reverse)})
+    ``_q_roundtrip`` up to the collective — identical census)."""
     qt = qlib.quantize(buf, bits, key, stochastic, scale_dtype, impl=impl)
     return exchange_quantized_halo(qt, plan, backend, reverse=reverse)
 
@@ -82,9 +77,7 @@ def _issue(buf, key, bits, stochastic, scale_dtype, backend, plan,
 def _land(inflight, backend, impl="auto"):
     """Land an in-flight exchange: fence, then dequantize the received
     payload. The fence pins consumption after the issue in program order
-    without touching the values. The obs event is trace-time, like
-    ``_issue``'s."""
-    obs.event("halo.land")
+    without touching the values."""
     return qlib.dequantize(fence(backend, inflight), impl=impl)
 
 

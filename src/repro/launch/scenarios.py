@@ -205,10 +205,9 @@ def run_cell(scn: Scenario, cell: Cell, *,
     ``obs_dir`` arms span tracing for this cell: the metrics registry is
     reset, the tracer runs for the whole train/eval, and
     ``<obs_dir>/<cell_id>.trace.json`` (Perfetto) +
-    ``<cell_id>.metrics.json`` (registry snapshot + modeled-vs-measured
-    join) are written; the report's ``trace_path`` points at the trace. The
-    ``obs`` block (measured wall per epoch vs modeled exposed/overlapped
-    comm) is present in *every* report — the obs clock works untraced too.
+    ``<cell_id>.metrics.json`` (registry snapshot) are written; the report's
+    ``trace_path`` points at the trace. The ``obs`` block (whether the cell
+    ran traced, and its epochs) is present in *every* report.
     """
     key = (cell.dataset, scn.parts, scn.seed)
     if loaded is None or key not in loaded:
@@ -255,8 +254,6 @@ def run_cell(scn: Scenario, cell: Cell, *,
         events = obs.drain()
         if traced:
             obs.disable()
-    mm = obs_export.modeled_vs_measured(
-        [m.wall_s for m in tr.history], exposed_s, overlapped_s)
     trace_path = None
     if traced:
         run_name = f"{scn.name}/{cell.cell_id}"
@@ -264,8 +261,7 @@ def run_cell(scn: Scenario, cell: Cell, *,
             Path(obs_dir) / f"{cell.cell_id}.trace.json", events))
         obs_export.write_metrics(
             Path(obs_dir) / f"{cell.cell_id}.metrics.json",
-            metrics=obs.snapshot(), run=run_name, merge=mm,
-            trace_path=trace_path)
+            metrics=obs.snapshot(), run=run_name, trace_path=trace_path)
     return {
         "schema_version": REPORT_SCHEMA_VERSION,
         "scenario": scn.name, "cell": cell.cell_id,
@@ -297,10 +293,8 @@ def run_cell(scn: Scenario, cell: Cell, *,
         "halos_reused": int(sum(m.halos_reused for m in tr.history)),
         "forced_syncs": int(sum(m.forced_syncs for m in tr.history)),
         "stall_s": float(sum(m.stall_s for m in tr.history)),
-        # measured-vs-modeled join (always present; the per-epoch rows live
-        # in the metrics artifact, the report carries the headline numbers)
-        "obs": {"enabled": traced, "n_epochs": mm["n_epochs"],
-                "mean_wall_s": mm["mean_wall_s"], "drift_s": mm["drift_s"]},
+        # whether the cell ran traced (its artifacts are at trace_path)
+        "obs": {"enabled": traced, "n_epochs": len(tr.history)},
         "trace_path": trace_path,
     }
 

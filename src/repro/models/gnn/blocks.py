@@ -6,6 +6,11 @@ over the leading partition axis (size 1 per device under shard_map; size P in th
 simulated single-process mode). This IS the SpMM/SDDMM layer of the system; the
 Pallas kernel in ``repro/kernels/spmm`` implements the same contract for the TPU
 hot path.
+
+The gathers over the edge list, the segment reductions and the per-edge
+softmax run under ``jax.named_scope("aggregation")``: the compiled program's
+``op_name`` metadata names every operation they lower to (``jvp(...)`` /
+``transpose(jvp(...))`` in the backward), so a profile can be split by layer.
 """
 from __future__ import annotations
 
@@ -18,6 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ...core.exchange import PlanArrays
+from ...core.scopes import scoped
 from ...graph.partition import PartitionedGraph, PartitionShapeSpec
 from . import so3
 
@@ -84,10 +90,12 @@ def halo_table(h: jax.Array, halo: jax.Array) -> jax.Array:
     return jnp.concatenate([h, halo], axis=1)
 
 
+@scoped("aggregation")
 def gather_src(block: GraphBlock, table: jax.Array) -> jax.Array:
     return jnp.take_along_axis(table, block.edges[..., 0:1], axis=1)
 
 
+@scoped("aggregation")
 def gather_dst(block: GraphBlock, h: jax.Array) -> jax.Array:
     return jnp.take_along_axis(h, block.edges[..., 1:2], axis=1)
 
@@ -96,39 +104,46 @@ def _seg(fn, msgs, dst, n_local):
     return jax.vmap(partial(fn, num_segments=n_local))(msgs, dst)
 
 
+@scoped("aggregation")
 def agg_sum(block: GraphBlock, msgs: jax.Array) -> jax.Array:
     msgs = jnp.where(block.edge_mask[..., None], msgs, 0)
     return _seg(jax.ops.segment_sum, msgs, block.edges[..., 1], block.n_local)
 
 
+@scoped("aggregation")
 def agg_max(block: GraphBlock, msgs: jax.Array) -> jax.Array:
     msgs = jnp.where(block.edge_mask[..., None], msgs, NEG)
     out = _seg(jax.ops.segment_max, msgs, block.edges[..., 1], block.n_local)
     return jnp.where(out <= NEG / 2, 0.0, out)
 
 
+@scoped("aggregation")
 def agg_min(block: GraphBlock, msgs: jax.Array) -> jax.Array:
     return -agg_max(block, -msgs)
 
 
+@scoped("aggregation")
 def degrees(block: GraphBlock) -> jax.Array:
     ones = block.edge_mask.astype(jnp.float32)
     return jax.vmap(partial(jax.ops.segment_sum, num_segments=block.n_local))(
         ones, block.edges[..., 1])
 
 
+@scoped("aggregation")
 def agg_mean(block: GraphBlock, msgs: jax.Array) -> jax.Array:
     s = agg_sum(block, msgs)
     d = degrees(block)
     return s / jnp.maximum(d, 1.0)[..., None]
 
 
+@scoped("aggregation")
 def agg_std(block: GraphBlock, msgs: jax.Array, eps: float = 1e-5) -> jax.Array:
     mu = agg_mean(block, msgs)
     mu2 = agg_mean(block, msgs * msgs)
     return jnp.sqrt(jnp.maximum(mu2 - mu * mu, 0.0) + eps)
 
 
+@scoped("aggregation")
 def edge_softmax(block: GraphBlock, scores: jax.Array) -> jax.Array:
     """Per-dst softmax over incoming edges; scores (P, E, H) -> alphas (P, E, H)."""
     dst = block.edges[..., 1]

@@ -5,13 +5,20 @@ layer may depend on this one):
 
 * :mod:`.spans` — the zero-overhead-when-disabled span/event tracer with an
   injectable monotonic clock (arm with :func:`enable`, read time through
-  :func:`clock`);
+  :func:`clock`). Training spans: ``epoch > decide``, ``epoch > build``,
+  ``epoch > step > dispatch``, ``epoch > step > readback.loss``,
+  ``epoch > readback.stats``; serving spans: ``request > lookup``,
+  ``admit``, ``refresh > plan > sweep``;
 * :mod:`.metrics` — the always-on typed counter/gauge/histogram registry,
   plus the :class:`TraceLog` list shims that superseded the two historical
   ``TRACE_LOG``s;
 * :mod:`.export` — Chrome/Perfetto ``trace_event`` JSON + flat metrics JSON
-  writers and the modeled-vs-measured drift join, rendered by
-  ``python -m repro.obs summarize|timeline|diff``.
+  writers, rendered by ``python -m repro.obs summarize|timeline|diff``.
+
+Every span also goes to a profiler sink where one is installed
+(:func:`set_sink`): :mod:`.profiler`, which the JAX-importing layers install
+on import, opens a ``jax.profiler.TraceAnnotation`` of the span's name while
+a profiler records, so the spans share the device trace's clock.
 """
 from .spans import (  # noqa: F401
     NULL_SPAN,
@@ -24,6 +31,7 @@ from .spans import (  # noqa: F401
     enable,
     enabled,
     event,
+    set_sink,
     span,
 )
 from .metrics import (  # noqa: F401
@@ -43,7 +51,6 @@ from .metrics import (  # noqa: F401
 )
 from .export import (  # noqa: F401
     default_obs_dir,
-    modeled_vs_measured,
     write_metrics,
     write_trace,
 )
@@ -51,9 +58,9 @@ from .export import (  # noqa: F401
 __all__ = [
     "NULL_SPAN", "FakeClock", "Tracer",
     "clock", "current", "disable", "drain", "enable", "enabled", "event",
-    "span",
+    "set_sink", "span",
     "REGISTRY", "Counter", "Gauge", "Histogram", "MetricsRegistry",
     "TraceLog", "count", "counter", "gauge", "histogram", "observe",
     "reset_metrics", "snapshot",
-    "default_obs_dir", "modeled_vs_measured", "write_metrics", "write_trace",
+    "default_obs_dir", "write_metrics", "write_trace",
 ]

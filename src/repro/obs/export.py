@@ -6,12 +6,8 @@ tracked README documents the layout), one pair per traced cell/run:
 * ``<name>.trace.json`` — Chrome ``trace_event`` format (open in Perfetto or
   ``chrome://tracing``): ``{"traceEvents": [{"name", "ph", "ts", "dur",
   "pid", "tid", "args"}], "displayTimeUnit": "ms"}``, timestamps in µs.
-* ``<name>.metrics.json`` — the metrics-registry snapshot plus the
-  **modeled-vs-measured join**: each epoch's measured wall-clock span against
-  the scenario report's ``modeled_tpu_comm_exposed_s`` / ``overlapped_s``, so
-  modeled-vs-reality drift is a single queryable number (``drift_s``) instead
-  of two JSON files someone has to correlate by hand. The file is
-  self-contained — :func:`render_summary` needs no scenario report.
+* ``<name>.metrics.json`` — the metrics-registry snapshot (counters,
+  gauges, histograms) of the run, with the path of its trace file.
 
 The CLI (``python -m repro.obs``) renders these: ``summarize`` tabulates
 every metrics file in a directory, ``timeline`` draws a trace as an ASCII
@@ -59,41 +55,13 @@ def write_trace(path, events: Sequence[dict], pid: int = 0) -> Path:
     return path
 
 
-def modeled_vs_measured(epoch_wall_s: Sequence[float], exposed_s: float,
-                        overlapped_s: float) -> dict:
-    """Join measured per-epoch wall time against the modeled comm split.
-
-    The modeled numbers are per-epoch constants (bytes/BW under the traced
-    decision; DESIGN §8/§14); the measured walls vary. ``drift_s`` =
-    mean measured wall − modeled exposed comm: the single number that says
-    how far the comm model sits from this machine's reality (large positive
-    on CPU, where compute dwarfs the modeled TPU wire time — that gap *is*
-    the §8 caveat, now queryable per run)."""
-    walls = [float(w) for w in epoch_wall_s]
-    mean_wall = sum(walls) / len(walls) if walls else 0.0
-    return {
-        "epochs": [{"epoch": i, "wall_s": w,
-                    "modeled_exposed_s": float(exposed_s),
-                    "modeled_overlapped_s": float(overlapped_s),
-                    "drift_s": w - float(exposed_s)}
-                   for i, w in enumerate(walls)],
-        "n_epochs": len(walls),
-        "mean_wall_s": mean_wall,
-        "modeled_exposed_s": float(exposed_s),
-        "modeled_overlapped_s": float(overlapped_s),
-        "drift_s": mean_wall - float(exposed_s),
-    }
-
-
 def write_metrics(path, *, metrics: dict, run: Optional[str] = None,
-                  merge: Optional[dict] = None,
                   trace_path: Optional[str] = None) -> Path:
-    """Write the flat metrics JSON (registry snapshot + optional
-    modeled-vs-measured join); returns the path."""
+    """Write the flat metrics JSON (registry snapshot); returns the path."""
     path = Path(path)
     path.parent.mkdir(parents=True, exist_ok=True)
     body = {"schema": SCHEMA, "run": run, "metrics": metrics,
-            "modeled_vs_measured": merge, "trace_path": trace_path}
+            "trace_path": trace_path}
     path.write_text(json.dumps(body, indent=1, default=float))
     return path
 
@@ -114,8 +82,7 @@ def metrics_files(directory) -> list[Path]:
 
 
 def render_summary(directory) -> str:
-    """One line per metrics file: measured epoch wall joined against the
-    modeled exposed/overlapped split, plus the headline counters."""
+    """One line per metrics file: the headline counters."""
     files = metrics_files(directory)
     if not files:
         raise FileNotFoundError(
@@ -123,22 +90,15 @@ def render_summary(directory) -> str:
             "--obs first (e.g. python -m repro.launch.train --scenario "
             "smoke --obs)")
     lines = [f"obs summary: {directory} ({len(files)} run(s))",
-             f"{'run':58s} {'epochs':>6s} {'wall/ep':>10s} "
-             f"{'exposed':>10s} {'overlap':>10s} {'drift':>10s} "
-             f"{'retrace':>7s}"]
+             f"{'run':58s} {'counters':>8s} {'retrace':>7s} {'faults':>7s}"]
     for f in files:
         body = load_metrics(f)
         run = body.get("run") or f.name[:-len(".metrics.json")]
-        mm = body.get("modeled_vs_measured") or {}
         counters = body.get("metrics", {}).get("counters", {})
         retraces = sum(v for k, v in counters.items()
                        if k.startswith("retrace."))
-        lines.append(
-            f"{run:58s} {mm.get('n_epochs', 0):6d} "
-            f"{mm.get('mean_wall_s', 0.0):9.4f}s "
-            f"{mm.get('modeled_exposed_s', 0.0):9.6f}s "
-            f"{mm.get('modeled_overlapped_s', 0.0):9.6f}s "
-            f"{mm.get('drift_s', 0.0):9.4f}s {retraces:7d}")
+        lines.append(f"{run:58s} {len(counters):8d} {retraces:7d} "
+                     f"{counters.get('faults.injected', 0):7g}")
     return "\n".join(lines)
 
 
@@ -190,10 +150,4 @@ def render_diff(path_a, path_b) -> str:
     for n in names:
         va, vb = ca.get(n, 0), cb.get(n, 0)
         lines.append(f"{n:40s} {va:12g} {vb:12g} {vb - va:+12g}")
-    ma = (a.get("modeled_vs_measured") or {})
-    mb = (b.get("modeled_vs_measured") or {})
-    if ma or mb:
-        da, db = ma.get("drift_s", 0.0), mb.get("drift_s", 0.0)
-        lines.append(f"{'drift_s':40s} {da:12.4f} {db:12.4f} "
-                     f"{db - da:+12.4f}")
     return "\n".join(lines)

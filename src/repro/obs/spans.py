@@ -5,18 +5,29 @@ One process-global :class:`Tracer` (armed via :func:`enable`, torn down via
 duration) and **instant events** into thread-local buffers. The taxonomy the
 instrumented layers emit:
 
-* training — ``epoch > decide > step`` (+ ``halo.issue``/``halo.land``
-  trace-time events from ``dist/overlap.py``, and ``retrace`` events from the
-  :class:`~repro.obs.metrics.TraceLog` shims);
+* training — ``epoch > decide``, ``epoch > build`` (a step-cache miss: the
+  decision's step is traced and compiled in that epoch's ``dispatch``),
+  ``epoch > step > dispatch`` (the jitted call until it returns) and
+  ``epoch > step > readback.loss`` (the blocking ``float(loss)``), then
+  ``epoch > readback.stats`` (the site-statistics ``device_get``); plus
+  ``retrace`` events from the :class:`~repro.obs.metrics.TraceLog` shims;
 * serving — ``request > lookup`` on the request path, ``admit`` on submit,
   ``refresh > plan > sweep`` on the update path.
 
+**Profiler sink.** A span can also open a profiler annotation of the same
+name, so that it lands in the profiler's trace on the clock of the device
+operations. The JAX-importing layers install that sink once
+(:func:`repro.obs.profiler.install`); it opens a
+``jax.profiler.TraceAnnotation`` only while a profiler records. Spans go to
+the sink whether or not a tracer is armed, and to the tracer's buffer (and
+the Perfetto JSON) only when one is.
+
 Design rules (DESIGN.md §15):
 
-* **disabled = free.** :func:`span` with no tracer armed returns one shared
-  :class:`_NullSpan` singleton — no allocation, no clock read, no branch
-  beyond the ``None`` check. ``args`` is a positional optional (never
-  ``**kwargs``) so the disabled call builds no dict.
+* **disabled = free.** :func:`span` with no tracer armed and no profiler
+  recording returns one shared :class:`_NullSpan` singleton — no allocation,
+  no clock read. ``args`` is a positional optional (never ``**kwargs``) so
+  the disabled call builds no dict.
 * **host-side only.** Instrumentation lives in host orchestration code or at
   trace time (the same seams as the ``TRACE_LOG`` appends); it must never
   lower into a traced program — contract RC210 holds training and serving
@@ -37,7 +48,11 @@ from __future__ import annotations
 
 import threading
 import time
-from typing import Any, Callable, Optional
+from typing import Any, Callable, ContextManager, Optional
+
+# A profiler sink maps a span name to a context manager that marks the span
+# in the profiler's trace, or to None where nothing records.
+Sink = Callable[[str], Optional[ContextManager]]
 
 
 class _NullSpan:
@@ -56,22 +71,29 @@ NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    """One live span: clocks itself on enter/exit, records on exit."""
+    """One live span: clocks itself on enter/exit, records on exit; opens and
+    closes the profiler's annotation around that, where there is one."""
 
-    __slots__ = ("_tracer", "name", "args", "_t0")
+    __slots__ = ("_tracer", "name", "args", "_t0", "_mark")
 
-    def __init__(self, tracer: "Tracer", name: str, args: Optional[dict]):
+    def __init__(self, tracer: "Tracer", name: str, args: Optional[dict],
+                 mark: Optional[ContextManager] = None):
         self._tracer = tracer
         self.name = name
         self.args = args
+        self._mark = mark
 
     def __enter__(self) -> "_Span":
+        if self._mark is not None:
+            self._mark.__enter__()
         self._t0 = self._tracer.clock()
         return self
 
     def __exit__(self, *exc) -> bool:
         t1 = self._tracer.clock()
         self._tracer._record(self.name, self._t0, t1 - self._t0, self.args)
+        if self._mark is not None:
+            self._mark.__exit__(*exc)
         return False
 
 
@@ -128,8 +150,9 @@ class Tracer:
             ev["args"] = args
         self._buf().append(ev)
 
-    def span(self, name: str, args: Optional[dict] = None) -> _Span:
-        return _Span(self, name, args)
+    def span(self, name: str, args: Optional[dict] = None,
+             mark: Optional[ContextManager] = None) -> _Span:
+        return _Span(self, name, args, mark)
 
     def event(self, name: str, args: Optional[dict] = None) -> None:
         ev: dict[str, Any] = {"name": name, "ph": "i", "ts": self.clock(),
@@ -153,6 +176,7 @@ class Tracer:
 # the process-global tracer (module functions are the instrumentation API)
 # ---------------------------------------------------------------------------
 _TRACER: Optional[Tracer] = None
+_SINK: Optional[Sink] = None
 
 
 def enable(clock: Optional[Callable[[], float]] = None) -> Tracer:
@@ -175,11 +199,23 @@ def current() -> Optional[Tracer]:
     return _TRACER
 
 
+def set_sink(sink: Optional[Sink]) -> Optional[Sink]:
+    """Install the profiler sink every span also goes to (``None`` removes
+    it). Returns the sink it replaces."""
+    global _SINK
+    prev, _SINK = _SINK, sink
+    return prev
+
+
 def span(name: str, args: Optional[dict] = None):
-    """A span context manager — :data:`NULL_SPAN` when tracing is off (the
+    """A span context manager: the tracer's span (with the sink's mark) when
+    a tracer is armed, else the sink's mark, else :data:`NULL_SPAN` (the
     allocation-free hot path)."""
-    t = _TRACER
-    return t.span(name, args) if t is not None else NULL_SPAN
+    t, sink = _TRACER, _SINK
+    mark = sink(name) if sink is not None else None
+    if t is not None:
+        return t.span(name, args, mark)
+    return mark if mark is not None else NULL_SPAN
 
 
 def event(name: str, args: Optional[dict] = None) -> None:

@@ -44,9 +44,13 @@ from ..core.sylvie import SylvieComm, SylvieConfig
 from ..dist.runtime import Runtime
 from ..graph.partition import PartitionedGraph, global_to_slot, khop_frontier
 from ..models.gnn import blocks as B
+from ..obs import profiler as obs_profiler
 from ..policy.base import EpochDecision, validate_decision
 from ..train import checkpoint as ckpt
 from . import delta as deltalib
+
+# host spans also land in the JAX profiler's trace, on the device clock
+obs_profiler.install()
 
 # Trace instrumentation, mirroring train.gnn_step.TRACE_LOG: the sweep body
 # appends once per jit trace. repro.analysis (RC204/RC207) counts entries to

@@ -43,6 +43,7 @@ from ..dist.runtime import Runtime
 from ..faults.backend import FaultyBackend
 from ..faults.plan import FaultCtl, FaultPlan, RowGeometry
 from ..models.gnn import blocks as B
+from ..obs import profiler as obs_profiler
 from ..policy.base import (CommPolicy, EpochDecision, SiteStats, Telemetry,
                            validate_decision)
 from ..policy.builtin import BoundedStaleness, Uniform
@@ -50,6 +51,9 @@ from . import checkpoint as ckpt
 from . import optimizer as optlib
 from .compression import ef_wire_bytes
 from .gnn_step import GNNTrainState, make_gnn_steps
+
+# host spans also land in the JAX profiler's trace, on the device clock
+obs_profiler.install()
 
 # EMA smoothing factor for the per-site range stats fed back to policies —
 # damps epoch-to-epoch jitter so adaptive bit assignments settle on one
@@ -83,9 +87,8 @@ class EpochMetrics:
     forced_syncs: int = 0
     stall_s: float = 0.0
     # measured whole-epoch wall time on the obs clock (decide + fault arming
-    # + step + telemetry absorption), vs ``seconds`` = the step call alone.
-    # Deterministic under an injected FakeClock; feeds the modeled-vs-measured
-    # join in repro.obs.export.
+    # + step + telemetry absorption), vs ``seconds`` = the step call alone
+    # (dispatch + loss read-back). Deterministic under an injected FakeClock.
     wall_s: float = 0.0
 
 
@@ -229,21 +232,26 @@ class GNNTrainer:
     def _steps_for(self, decision: EpochDecision):
         """(train_sync, train_async) compiled for this decision. Cached on
         ``decision.step_key()`` (sync excluded — it picks *which* step runs),
-        so distinct executables are bounded by distinct lattice points."""
+        so distinct executables are bounded by distinct lattice points. A miss
+        opens the ``build`` span; the new step is traced and compiled in the
+        ``dispatch`` that first calls it."""
         key = decision.step_key()
         if key not in self._step_cache:
-            ts, ta, ev = make_gnn_steps(self.model, self.cfg, self.opt,
-                                        backend=self.runtime.backend,
-                                        decision=decision)
-            ts, ta, _ = self.runtime.shard_gnn_steps(ts, ta, ev, self.state,
-                                                     self.block)
+            with obs.span("build"):
+                ts, ta, ev = make_gnn_steps(self.model, self.cfg, self.opt,
+                                            backend=self.runtime.backend,
+                                            decision=decision)
+                ts, ta, _ = self.runtime.shard_gnn_steps(ts, ta, ev,
+                                                         self.state,
+                                                         self.block)
             self._step_cache[key] = (ts, ta)
         return self._step_cache[key]
 
     def _absorb_site_stats(self):
         """Fold the step's emitted (n_sites, 2) [sum range^2, live rows] into
         the EMA-smoothed SiteStats telemetry."""
-        raw = np.asarray(jax.device_get(self.state.site_stats))
+        with obs.span("readback.stats"):
+            raw = np.asarray(jax.device_get(self.state.site_stats))
         rows = self.block.plan.real_rows
         cur = []
         for i, d in enumerate(self.site_dims):
@@ -352,7 +360,10 @@ class GNNTrainer:
 
     def train_epoch(self) -> EpochMetrics:
         w0 = obs.clock()
-        with obs.span("epoch", {"epoch": self.epoch}):
+        # the profiler's step marker ("train", step_num = epoch) around the
+        # epoch span
+        with jax.profiler.StepTraceAnnotation("train", step_num=self.epoch), \
+                obs.span("epoch", {"epoch": self.epoch}):
             with obs.span("decide"):
                 decision = self._decide()
             injected = reused = forced = 0
@@ -369,9 +380,12 @@ class GNNTrainer:
             t0 = obs.clock()
             with obs.span("step",
                           {"mode": "sync" if decision.sync else "async"}):
-                self.state, loss = fn(self.state, self.block, self.x, self.y,
-                                      self.train_mask, self._epoch_key())
-                loss = float(loss)
+                with obs.span("dispatch"):
+                    self.state, loss = fn(self.state, self.block, self.x,
+                                          self.y, self.train_mask,
+                                          self._epoch_key())
+                with obs.span("readback.loss"):
+                    loss = float(loss)
             dt = obs.clock() - t0
             self._needs_sync = False
             if escalate:

@@ -145,10 +145,7 @@ def test_traced_cell_writes_obs_artifacts_with_full_schema(tmp_path):
     assert set(rep) == S.REPORT_KEYS
     assert rep["schema_version"] == S.REPORT_SCHEMA_VERSION
     assert rep["obs"]["enabled"] is True
-    assert rep["obs"]["n_epochs"] == 2 and rep["obs"]["mean_wall_s"] > 0.0
-    # drift = measured wall - modeled exposed comm; CPU wall time dwarfs the
-    # modeled TPU wire time, so the drift is large and positive by design
-    assert rep["obs"]["drift_s"] > 0.0
+    assert rep["obs"]["n_epochs"] == 2
     trace = obs_dir / f"{cell.cell_id}.trace.json"
     metrics = obs_dir / f"{cell.cell_id}.metrics.json"
     assert rep["trace_path"] == str(trace)
@@ -156,7 +153,7 @@ def test_traced_cell_writes_obs_artifacts_with_full_schema(tmp_path):
     assert {"epoch", "decide", "step"} <= names
     body = ox.load_metrics(metrics)
     assert body["run"] == f"one/{cell.cell_id}"
-    assert body["modeled_vs_measured"]["n_epochs"] == 2
+    assert body["trace_path"] == str(trace)
     assert body["metrics"]["counters"]["retrace.train"] >= 1
     summary = ox.render_summary(obs_dir)
     assert f"one/{cell.cell_id}" in summary

@@ -43,13 +43,13 @@
 #                              kill-and-resume), then the chaos launcher's
 #                              own self-check (repro.launch.chaos --ci)
 #   tools/ci.sh --obs          observability lane: repro.obs suite (span
-#                              tracer, metrics registry, exporters, CLI,
-#                              instrumented layers), then a traced smoke
-#                              scenario slice (--obs writes Perfetto trace +
-#                              metrics JSON under artifacts/obs/smoke/)
-#                              rendered by `python -m repro.obs summarize`
-#                              (exit-code gated), then the bench_obs smoke
-#                              gate (disabled-tracer overhead <= 1%)
+#                              tracer, profiler sink, metrics registry,
+#                              exporters, CLI, instrumented layers and named
+#                              scopes), then a traced smoke scenario slice
+#                              (--obs writes Perfetto trace + metrics JSON
+#                              under artifacts/obs/smoke/) rendered by
+#                              `python -m repro.obs summarize` (exit-code
+#                              gated)
 #   tools/ci.sh --docs         documentation lane: markdown link check over
 #                              README/DESIGN/CHANGES + execution of every
 #                              README ```bash block (quickstart, scenario
@@ -113,8 +113,7 @@ case "${1:-}" in
     XLA_FLAGS="--xla_force_host_platform_device_count=4${XLA_FLAGS:+ $XLA_FLAGS}" \
       python -m pytest -x -q tests/test_obs.py -m "not slow" "$@"
     python -m repro.launch.train --scenario smoke --only gcn__yelp_like --obs
-    python -m repro.obs summarize artifacts/obs/smoke
-    exec python -m benchmarks.bench_obs --smoke
+    exec python -m repro.obs summarize artifacts/obs/smoke
     ;;
   --docs)
     shift
