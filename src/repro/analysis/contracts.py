@@ -87,15 +87,15 @@ def _buckets(pg, layout: str) -> Optional[tuple[int, ...]]:
     return tuple(int(b) for b in pg.plan.bucket_sizes)
 
 
-def _train_exp(model, state, pg, layout: str, bits: int,
-               *, sync: bool) -> ExchangeExpectation:
+def _train_exp(model, state, pg, layout: str,
+               bits: int) -> ExchangeExpectation:
     """Declared comm structure of a train step.
 
-    Forward: one exchange per site. Backward (sync): the site-0 exchange
-    ships raw input features for GCN/SAGE, which carry no gradient, so its
-    backward exchange is dead-code-eliminated — ``n_sites - 1`` ops. Async
-    steps exchange the *gradient caches* instead, and every cache (site 0
-    included) is a differentiated output, so nothing is eliminated.
+    Forward: one exchange per site. Backward: the site-0 exchange ships raw
+    input features for GCN/SAGE, which carry no gradient, so its backward
+    exchange is dead-code-eliminated — ``n_sites - 1`` ops. Async steps
+    exchange the *gradient caches* instead; the site fed by the features
+    consumes its cache as plain data, so the same site drops out there too.
     psums: one per weight-grad leaf (Alg. 2 line 16) + 2 for the masked loss
     (sum, count) + 1 for the site telemetry.
     """
@@ -103,7 +103,7 @@ def _train_exp(model, state, pg, layout: str, bits: int,
     n_leaves = len(jax.tree.leaves(state.params))
     return ExchangeExpectation(
         fwd_ops=n_sites,
-        bwd_ops=n_sites - 1 if sync else n_sites,
+        bwd_ops=n_sites - 1,
         bits=bits, buckets=_buckets(pg, layout), psums=n_leaves + 3)
 
 
@@ -122,7 +122,7 @@ def contract_train_census(arch: str, layout: str
     ts, ta, ev = make_gnn_steps(model, cfg, opt, backend=rt.backend)
     ts, _, _ = rt.shard_gnn_steps(ts, ta, ev, state, *args[:1])
     summary = summarize(jax.make_jaxpr(ts)(state, *args))
-    exp = _train_exp(model, state, pg, layout, bits=1, sync=True)
+    exp = _train_exp(model, state, pg, layout, bits=1)
     return (check_exchange_census(summary, exp, where)
             + check_wire_dtypes(summary, exp, where)
             + check_no_callbacks(summary, where)), []
@@ -140,7 +140,7 @@ def contract_train_async_census() -> tuple[list[Finding], list[str]]:
     ts, ta, ev = make_gnn_steps(model, cfg, opt, backend=rt.backend)
     _, ta, _ = rt.shard_gnn_steps(ts, ta, ev, state, *args[:1])
     summary = summarize(jax.make_jaxpr(ta)(state, *args))
-    exp = _train_exp(model, state, pg, "compact", bits=1, sync=False)
+    exp = _train_exp(model, state, pg, "compact", bits=1)
     return (check_exchange_census(summary, exp, where)
             + check_wire_dtypes(summary, exp, where)
             + check_no_callbacks(summary, where)), []
@@ -384,7 +384,7 @@ def contract_overlap_census() -> tuple[list[Finding], list[str]]:
     ts, ta, ev = make_gnn_steps(model, cfg, opt, backend=rt.backend)
     ts, _, _ = rt.shard_gnn_steps(ts, ta, ev, state, *args[:1])
     summary = summarize(jax.make_jaxpr(ts)(state, *args))
-    exp = _train_exp(model, state, pg, "compact", bits=1, sync=True)
+    exp = _train_exp(model, state, pg, "compact", bits=1)
     findings = (check_exchange_census(summary, exp, where)
                 + check_wire_dtypes(summary, exp, where)
                 + check_no_callbacks(summary, where))

@@ -12,7 +12,10 @@ Three communication modes (paper §3):
   new cache for the next step, so XLA can overlap it with compute.
   Backward mirrors it: the cotangent on the stale halo is exchanged and
   surfaces as the gradient of a zero-valued ``gslot`` input, becoming the
-  next step's ``grad_in`` (one-step-stale boundary gradients).
+  next step's ``grad_in`` (one-step-stale boundary gradients). A site whose
+  input is the step's own node features sends no boundary gradient: the
+  features are data, so nothing reads that gradient, and the site consumes
+  its cache without ``stale_halo``, leaving autodiff no backward to run.
 
 What each exchange site does in a given epoch — forward/backward bit-widths,
 stochastic vs deterministic rounding, BNS boundary sampling — is a
@@ -48,6 +51,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from .. import obs
 from ..dist.backend import as_backend
 from ..policy.base import SiteDecision
 from . import quantization as qlib
@@ -194,12 +198,18 @@ class SylvieComm:
     ``sites[i]`` drives the i-th ``halo`` call; ``None`` falls back to the one
     global ``SylvieConfig`` choice for every site (the Uniform shim).
     Collects fresh caches (async mode) and — when ``collect_stats`` — per-site
-    boundary range statistics as it goes."""
+    boundary range statistics as it goes.
+
+    ``inputs`` is the step's own feature input (data, never differentiated).
+    An async site handed exactly that array takes no gradient: it consumes
+    its stale cache as plain data, so autodiff has no table transpose,
+    backward Low-bit round trip or boundary scatter to run for it, and its
+    ``gslot`` gradient (the next step's ``grad_in``) is zero."""
 
     def __init__(self, cfg: SylvieConfig, plan: PlanArrays, key,
                  backend=None, decision=None, collect_stats=False,
                  feat_caches=None, grad_ins=None, gslots=None,
-                 fault_sites=None):
+                 fault_sites=None, inputs=None):
         self.cfg = cfg
         self.plan = plan
         self.key = key
@@ -212,6 +222,7 @@ class SylvieComm:
         # per-site fault masks (repro.faults.plan.SiteFaults tuple) riding as
         # data; None = fault-free, traces the exact legacy program.
         self.fault_sites = fault_sites
+        self.inputs = inputs
         self.new_feat_caches: list = []
         self.site_stats: list = []
         self._site = 0
@@ -307,30 +318,41 @@ class SylvieComm:
             self.new_feat_caches.append(halo)
             return halo
         # async: consume stale, emit fresh
-        if sf is not None:
+        if h is self.inputs:
+            # The node features take no gradient, so this site's boundary
+            # gradients, outgoing and incoming, would feed nothing: consume
+            # the cache as plain data and leave autodiff no backward to run.
+            # (Stopping only the gslot gradient would still keep grad_in
+            # as an operand, and so as a live argument of the step.)
+            obs.count("halo.bwd_pruned")
+            halo = self.feat_caches[i]
+        elif sf is not None:
             halo = fcomm.faulty_stale_halo(
                 h, self.feat_caches[i], self.grad_ins[i], self.gslots[i], sf,
                 self.plan, kb, sd.bwd_bits, sd.stochastic, cfg.scale_dtype,
                 self.backend, cfg.quant_impl)
-            self.new_feat_caches.append(fcomm.faulty_fresh_halo(
-                h, self.feat_caches[i], sf, self.plan, kf, sd.fwd_bits,
-                sd.stochastic, cfg.scale_dtype, self.backend, cfg.quant_impl))
-            return halo
-        if overlap:
+        elif overlap:
             halo = olap.overlap_stale_halo(
                 h, self.feat_caches[i], self.grad_ins[i], self.gslots[i],
                 self.plan, kb, sd.bwd_bits, sd.stochastic, cfg.scale_dtype,
                 self.backend, cfg.quant_impl)
-            self.new_feat_caches.append(olap.overlap_fresh_halo(
+        else:
+            halo = stale_halo(h, self.feat_caches[i], self.grad_ins[i],
+                              self.gslots[i], self.plan, kb, sd.bwd_bits,
+                              sd.stochastic, cfg.scale_dtype, self.backend,
+                              cfg.quant_impl)
+        if sf is not None:
+            fresh = fcomm.faulty_fresh_halo(
+                h, self.feat_caches[i], sf, self.plan, kf, sd.fwd_bits,
+                sd.stochastic, cfg.scale_dtype, self.backend, cfg.quant_impl)
+        elif overlap:
+            fresh = olap.overlap_fresh_halo(
                 h, self.plan, kf, sd.fwd_bits, sd.stochastic,
-                cfg.scale_dtype, self.backend, cfg.quant_impl))
-            return halo
-        halo = stale_halo(h, self.feat_caches[i], self.grad_ins[i], self.gslots[i],
-                          self.plan, kb, sd.bwd_bits, sd.stochastic,
-                          cfg.scale_dtype, self.backend, cfg.quant_impl)
-        self.new_feat_caches.append(
-            fresh_halo(h, self.plan, kf, sd.fwd_bits, sd.stochastic,
-                       cfg.scale_dtype, self.backend, cfg.quant_impl))
+                cfg.scale_dtype, self.backend, cfg.quant_impl)
+        else:
+            fresh = fresh_halo(h, self.plan, kf, sd.fwd_bits, sd.stochastic,
+                               cfg.scale_dtype, self.backend, cfg.quant_impl)
+        self.new_feat_caches.append(fresh)
         return halo
 
     @property

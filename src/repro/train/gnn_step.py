@@ -8,7 +8,9 @@ Three step flavors over one :class:`GNNTrainState`:
   epochs) and *drains* the grad caches (a synchronous epoch leaves no
   in-flight boundary gradients).
 * ``train_step_async`` — Sylvie-A: consumes cached halo features/gradients,
-  emits fresh caches for the next step.
+  emits fresh caches for the next step. A site fed by the node features
+  ``x`` sends no boundary gradient (``SylvieComm``'s ``inputs``); its
+  gradient cache reads zeros, as after a sync step.
 * ``eval_step``        — full-precision synchronous exchange (accuracy metric).
 
 What each halo-exchange site does — per-direction bit-widths, rounding mode,
@@ -176,7 +178,8 @@ def make_gnn_steps(model, cfg: SylvieConfig, opt: optlib.Optimizer,
                               grad_ins=state.halo.grads, gslots=gslots,
                               fault_sites=(state.faults.sites
                                            if state.faults is not None
-                                           else None))
+                                           else None),
+                              inputs=x)
             logits = model.apply(params, block, x, comm)
             loss = _masked_loss(logits, y, mask, backend)
             caches = tuple(jax.lax.stop_gradient(c) for c in comm.new_feat_caches)
@@ -185,6 +188,9 @@ def make_gnn_steps(model, cfg: SylvieConfig, opt: optlib.Optimizer,
         grad_fn = jax.value_and_grad(loss_fn, argnums=(0, 1), has_aux=True)
         ((loss, (caches, stats)),
          (pgrads, ggrads)) = grad_fn(state.params, state.halo.gslots())
+        # A site fed by x never reads its gslot, so its slot here is zeros.
+        # Passing the incoming cache through instead would keep that cache a
+        # live argument of the step, which donates nothing, and still copy it.
         new_halo = HaloState(feats=caches, grads=ggrads)
         return _finish(state, pgrads, loss, new_halo, stats)
 

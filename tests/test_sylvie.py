@@ -1,9 +1,13 @@
 """Sylvie core: halo exchange semantics, quantized custom_vjp, staleness."""
+import dataclasses
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.core import quantization as q
 from repro.core.exchange import (PlanArrays, exchange, gather_boundary,
                                  scatter_boundary_grad)
@@ -11,7 +15,7 @@ from repro.core.staleness import HaloState, use_sync_step
 from repro.core.sylvie import SylvieComm, SylvieConfig, quantized_halo
 from repro.graph import formats, partition, synthetic
 from repro.models.gnn import blocks as B
-from repro.models.gnn.models import GCN
+from repro.models.gnn.models import GAT, GCN
 from repro.train import optimizer as opt
 from repro.train.gnn_step import GNNTrainState, make_gnn_steps
 
@@ -156,3 +160,92 @@ def test_halo_state_pytree():
     leaves = jax.tree.leaves(hs)
     assert len(leaves) == 4
     assert all(l.shape[0] == 2 for l in leaves)
+
+
+@dataclasses.dataclass(frozen=True)
+class _FeatureCopy:
+    """Hands the wrapped model ``x + 0.0``: equal values, but a new array, so
+    the first exchange no longer sees the step's own feature input and keeps
+    its backward (the pre-pruning program)."""
+    inner: object
+
+    def comm_dims(self):
+        return self.inner.comm_dims()
+
+    def init(self, key):
+        return self.inner.init(key)
+
+    def apply(self, params, block, x, comm):
+        return self.inner.apply(params, block, x + 0.0, comm)
+
+
+def _async_run(model, schedule="blocking", epochs=4):
+    """One sync warm-up epoch, then ``epochs`` async 1-bit stochastic epochs;
+    returns (state, losses, jitted async step, data args)."""
+    _, pg, block = _setup(n=400, p=4, d=24, seed=3)
+    o = opt.adam(1e-2)
+    cfg = SylvieConfig(mode="async", bits=1, schedule=schedule)
+    ts, ta, _ = make_gnn_steps(model, cfg, o)
+    st = GNNTrainState.create(model, o, KEY, block.plan, stacked_parts=4)
+    args = (block, jnp.asarray(pg.x), jnp.asarray(pg.y),
+            jnp.asarray(pg.train_mask))
+    st, _ = jax.jit(ts)(st, *args, KEY)
+    ta = jax.jit(ta)
+    losses = []
+    for i in range(epochs):
+        st, loss = ta(st, *args, jax.random.fold_in(KEY, i))
+        losses.append(float(loss))
+    return st, losses, ta, args
+
+
+@pytest.mark.parametrize("schedule", ["blocking", "overlap"])
+def test_async_feature_site_pruning_is_exact(schedule):
+    """Pruning the backward of the site fed by the node features changes no
+    loss and no parameter, to the bit; only that site's gradient cache
+    differs: it reads zeros, where the unpruned program fills it with
+    gradients nothing reads."""
+    model = GCN(d_in=24, d_hidden=32, d_out=7)
+    st, losses, _, _ = _async_run(model, schedule)
+    st_ref, losses_ref, _, _ = _async_run(_FeatureCopy(model), schedule)
+    assert losses == losses_ref
+    for a, b in zip(jax.tree.leaves(st.params), jax.tree.leaves(st_ref.params)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    np.testing.assert_array_equal(np.asarray(st.halo.grads[1]),
+                                  np.asarray(st_ref.halo.grads[1]))
+    assert not np.any(np.asarray(st.halo.grads[0]))
+    assert np.any(np.asarray(st_ref.halo.grads[0]))
+
+
+def _scatter_widths(hlo: str) -> list[int]:
+    return [int(w) for w in re.findall(
+        r"f32\[(?:\d+,)*(\d+)\]\{[\d,]*\} scatter\(", hlo)]
+
+
+def test_async_feature_site_drops_its_input_width_scatter():
+    """The compiled async GCN step holds one scatter at the input width fewer
+    than the unpruned program (the transpose into the [x ; halo] table) and
+    no more argument bytes (the pruned site's incoming gradient cache is not
+    read), and each trace counts one pruned site."""
+    model = GCN(d_in=24, d_hidden=32, d_out=7)
+    widths, arg_bytes = {}, {}
+    for name, m in (("pruned", model), ("unpruned", _FeatureCopy(model))):
+        obs.reset_metrics()
+        st, _, ta, args = _async_run(m, epochs=1)
+        assert obs.counter("halo.bwd_pruned").value == (
+            1 if name == "pruned" else 0)
+        compiled = ta.lower(st, *args, KEY).compile()
+        widths[name] = _scatter_widths(compiled.as_text())
+        arg_bytes[name] = compiled.memory_analysis().argument_size_in_bytes
+    assert widths["pruned"].count(24) == widths["unpruned"].count(24) - 1
+    assert widths["pruned"].count(32) == widths["unpruned"].count(32)
+    assert arg_bytes["pruned"] <= arg_bytes["unpruned"]
+
+
+def test_async_gat_keeps_feature_site_backward():
+    """GAT exchanges x·W, which does take a gradient: its first site is not
+    pruned and still sends boundary gradients."""
+    obs.reset_metrics()
+    st, _, _, _ = _async_run(GAT(d_in=24, d_hidden=8, d_out=7, heads=2),
+                             epochs=1)
+    assert obs.counter("halo.bwd_pruned").value == 0
+    assert np.any(np.asarray(st.halo.grads[0]))
