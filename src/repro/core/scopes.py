@@ -1,8 +1,10 @@
 """Named scopes that split the compiled step by layer: ``aggregation``
-(``models/gnn/blocks.py``), ``lowbit`` (``core/quantization.py``) and
-``exchange`` (``core/exchange.py``). They are metadata only: the names reach
-the optimised program's ``op_name`` and, through it, the profiler's device
-events; the program itself is the same without them."""
+(``models/gnn/blocks.py``), ``lowbit`` (``core/quantization.py``),
+``exchange`` (``core/exchange.py``) and ``collective`` (``dist/backend.py``:
+the sharded path's collectives, inside ``exchange`` where they move halos).
+They are metadata only: the names reach the optimised program's ``op_name``
+and, through it, the profiler's device events; the program itself is the
+same without them."""
 from __future__ import annotations
 
 import functools

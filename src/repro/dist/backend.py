@@ -116,6 +116,15 @@ def _exchange_quantized(exch, qt: "QuantizedTensor") -> "QuantizedTensor":
         bits=qt.bits, feat_dim=qt.feat_dim)
 
 
+def _collective(fn, *args):
+    """``fn(*args)`` under ``jax.named_scope("collective")``: the cross-device
+    collectives of the sharded path, nested inside the ``exchange`` scope
+    where they move halos. Deferred import for the same cycle as
+    ``_exchange_quantized``."""
+    from ..core.scopes import scoped
+    return scoped("collective")(fn)(*args)
+
+
 def _bucket_slices(bucket_sizes: tuple[int, ...]):
     """(ring offset k, start, stop) for each non-empty bucket."""
     out, start = [], 0
@@ -222,8 +231,9 @@ class ShardMapBackend:
         return self.axes if self.axes is not None else tuple(self.mesh.axis_names)
 
     def exchange(self, buf: jax.Array) -> jax.Array:
-        return jax.lax.all_to_all(buf, self.axis_names, split_axis=1,
-                                  concat_axis=1, tiled=True)
+        return _collective(partial(jax.lax.all_to_all,
+                                   axis_name=self.axis_names, split_axis=1,
+                                   concat_axis=1, tiled=True), buf)
 
     def exchange_compact(self, buf: jax.Array, bucket_sizes: tuple[int, ...],
                          reverse: bool = False) -> jax.Array:
@@ -238,7 +248,9 @@ class ShardMapBackend:
         for k, s0, s1 in _bucket_slices(bucket_sizes):
             kk = (p - k) % p if reverse else k
             perm = [(src, (src + kk) % p) for src in range(p)]
-            parts.append(jax.lax.ppermute(buf[:, s0:s1], axis, perm))
+            parts.append(_collective(partial(jax.lax.ppermute,
+                                             axis_name=axis, perm=perm),
+                                     buf[:, s0:s1]))
         return jnp.concatenate(parts, axis=1) if parts else buf
 
     def exchange_quantized(self, qt: QuantizedTensor) -> QuantizedTensor:
@@ -251,7 +263,7 @@ class ShardMapBackend:
             lambda b: self.exchange_compact(b, bucket_sizes, reverse), qt)
 
     def psum(self, x: jax.Array) -> jax.Array:
-        return _rep_psum(x, self.axis_names)
+        return _collective(_rep_psum, x, self.axis_names)
 
     def fence(self, tree: Any) -> Any:
         return jax.lax.optimization_barrier(tree)
