@@ -73,7 +73,8 @@ def _workload(arch: str, layout: str):
     pg = partition(g, N_PARTS, method="skewed", layout=layout, alignment=4)
     model = ARCHS[arch](8, g.n_classes)
     opt = optlib.sgd(1e-1)
-    block = B.build_block(pg)
+    block = B.build_block(
+        pg, transposed=getattr(model, "transposed_edges", False))
     state = GNNTrainState.create(model, opt, jax.random.PRNGKey(0),
                                  block.plan, stacked_parts=N_PARTS)
     args = (block, jnp.asarray(pg.x), jnp.asarray(pg.y),

@@ -40,7 +40,8 @@ from ..graph.partition import HaloPlan, PartitionedGraph, partition_graph
 
 # Bump on any change to the serialization below or to partition_graph's
 # output for identical inputs — old entries then simply stop being referenced.
-CACHE_VERSION = 1
+# v2: each partition's edges sorted by destination, padding at the last rows.
+CACHE_VERSION = 2
 
 
 def default_cache_dir() -> Path:

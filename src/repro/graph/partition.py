@@ -100,7 +100,10 @@ class PartitionedGraph:
     train_mask: Optional[np.ndarray]
     val_mask: Optional[np.ndarray]
     test_mask: Optional[np.ndarray]
-    edges: np.ndarray             # (P, e_pad, 2) int32  [src_ext, dst_local]
+    # (P, e_pad, 2) int32 [src_ext, dst_local]: each partition's edges sorted
+    # by destination, each destination's in-edges in edge order; the padding
+    # edges follow and address the last local row and the last table row
+    edges: np.ndarray
     edge_mask: np.ndarray         # (P, e_pad)
     edge_weight: Optional[np.ndarray]  # (P, e_pad)
     pos: Optional[np.ndarray] = None    # (P, n_local, 3)
@@ -252,7 +255,7 @@ def partition_graph(g: Graph, n_parts: int, method: str = "block",
     ew = None if edge_weight is None else np.zeros((n_parts, e_pad), dtype=np.float32)
     ea = None if g.edge_attr is None else np.zeros(
         (n_parts, e_pad) + g.edge_attr.shape[1:], dtype=g.edge_attr.dtype)
-    eorder = np.argsort(p_dst, kind="stable")
+    eorder = np.argsort(p_dst * n_local + dst_loc, kind="stable")
     estarts = np.zeros(n_parts + 1, dtype=np.int64)
     np.cumsum(e_counts, out=estarts[1:])
     for p in range(n_parts):
@@ -278,6 +281,7 @@ def partition_graph(g: Graph, n_parts: int, method: str = "block",
                     layout=layout, bucket_sizes=bucket_sizes,
                     pair_counts=pair_counts,
                     alignment=alignment if layout == "compact" else 1)
+    edges[~edge_mask] = (n_local + plan.halo_rows - 1, n_local - 1)
     return PartitionedGraph(
         plan=plan, part_of=part_of, global_ids=global_ids, node_mask=node_mask,
         x=scatter_nodes(g.x),

@@ -243,9 +243,10 @@ def _gnn_cell(spec: ArchSpec, cell: ShapeCell, mesh, *,
     p_n = meshlib.n_devices(mesh)
     pspec = analytic_partition_spec(n, e, p_n)
 
-    block = B.block_spec(pspec, d_edge_attr=arch.d_edge_attr,
-                         with_weight=True, stacked_parts=p_n)
     model = arch.make(d_feat, n_classes)
+    block = B.block_spec(pspec, d_edge_attr=arch.d_edge_attr,
+                         with_weight=True, stacked_parts=p_n,
+                         transposed=getattr(model, "transposed_edges", False))
     opt = optlib.adam(1e-2)
     scfg = SylvieConfig(mode=sylvie_mode, bits=bits)
     backend = dist.ShardMapBackend(mesh)

@@ -38,6 +38,8 @@ class GCN:
     d_hidden: int
     d_out: int
     n_layers: int = 2
+    # agg_weighted's backward reads the block's edges sorted by source
+    transposed_edges = True
 
     def comm_dims(self):
         return [self.d_in] + [self.d_hidden] * (self.n_layers - 1)
@@ -52,8 +54,7 @@ class GCN:
         h = x
         for i in range(self.n_layers):
             table = _exchange_and_table(comm, block, h)
-            src = B.gather_src(block, table) * block.edge_weight[..., None]
-            z = B.agg_sum(block, src)
+            z = B.agg_weighted(block, table)
             h = nn.linear(params[f"layer{i}"], z)
             if i < self.n_layers - 1:
                 h = jax.nn.relu(h)
@@ -68,6 +69,8 @@ class GraphSAGE:
     d_hidden: int
     d_out: int
     n_layers: int = 2
+    # agg_weighted's backward reads the block's edges sorted by source
+    transposed_edges = True
 
     def comm_dims(self):
         return [self.d_in] + [self.d_hidden] * (self.n_layers - 1)
@@ -84,8 +87,7 @@ class GraphSAGE:
         h = x
         for i in range(self.n_layers):
             table = _exchange_and_table(comm, block, h)
-            src = B.gather_src(block, table)
-            agg = B.agg_mean(block, src)
+            agg = B.agg_weighted(block, table, mean=True)
             h = nn.linear(params[f"layer{i}"]["self"], h) \
                 + nn.linear(params[f"layer{i}"]["nb"], agg)
             if i < self.n_layers - 1:

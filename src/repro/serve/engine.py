@@ -198,7 +198,7 @@ class InferenceEngine:
                                   scale_dtype=cfg.scale_dtype,
                                   quant_impl=cfg.quant_impl,
                                   schedule=self.decision.schedule)
-        self.block = B.build_block(pg)
+        self.block = B.build_block(pg, transposed=False)   # forward only
         self.key = jax.random.PRNGKey(seed)
 
         # global id -> (partition, local slot): the O(lookup) request path

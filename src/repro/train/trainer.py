@@ -162,7 +162,8 @@ class GNNTrainer:
         self.keep = keep
         self.key = jax.random.PRNGKey(seed)
 
-        self.block = B.build_block(pg)
+        self.block = B.build_block(
+            pg, transposed=getattr(model, "transposed_edges", False))
         self.x = jnp.asarray(pg.x)
         self.y = jnp.asarray(pg.y)
         self.train_mask = jnp.asarray(pg.train_mask)

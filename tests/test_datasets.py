@@ -115,6 +115,21 @@ def test_plan_cache_miss_then_hit_round_trips(tmp_path):
     assert pg2.plan.send_idx.dtype == pg1.plan.send_idx.dtype
 
 
+def test_plan_cache_never_loads_an_entry_of_another_version(tmp_path,
+                                                           monkeypatch):
+    """An entry written before the edges were sorted by destination (cache
+    version 1) is never referenced: the version is part of the key."""
+    g = registry.load("yelp_like@smoke")
+    monkeypatch.setattr(plans, "CACHE_VERSION", 1)
+    assert not plans.cached_partition(g, 4, cache_dir=tmp_path)[1]
+    monkeypatch.undo()
+    pg, hit = plans.cached_partition(g, 4, cache_dir=tmp_path)
+    assert not hit and len(list(tmp_path.glob("*.npz"))) == 2
+    for p in range(4):
+        k = int(pg.edge_mask[p].sum())
+        assert (np.diff(pg.edges[p, :k, 1]) >= 0).all()
+
+
 def test_cached_partition_trains_identically(tmp_path):
     """A cache-loaded PartitionedGraph is a drop-in for a fresh one."""
     from repro.core.sylvie import SylvieConfig
