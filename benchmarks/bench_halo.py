@@ -51,16 +51,16 @@ def _bench_layout(pg, d_feat, bits, reps):
 
     @jax.jit
     def fwd(x):
-        return quantized_halo(x, plan, k1, k2, bits, bits, True, jnp.bfloat16,
-                              None, "jnp")
+        return quantized_halo(x, None, plan, k1, k2, bits, bits, True,
+                              jnp.bfloat16, None, "jnp", False)
 
     @jax.jit
     def fwdbwd(x):
         # quadratic loss: the backward cotangent depends on x, so XLA cannot
         # constant-fold the quantized backward communication away
         return jax.grad(lambda v: (quantized_halo(
-            v, plan, k1, k2, bits, bits, True, jnp.bfloat16, None,
-            "jnp") ** 2).sum() / 2)(x)
+            v, None, plan, k1, k2, bits, bits, True, jnp.bfloat16, None,
+            "jnp", False) ** 2).sum() / 2)(x)
 
     pb, eb = wire_bytes(plan, d_feat, bits)
     tb, _ = exchange_bytes(plan, d_feat, bits)

@@ -51,9 +51,6 @@ from ..report import Finding
 TRACED_MODULES = (
     "src/repro/core/",
     "src/repro/kernels/",
-    "src/repro/dist/overlap.py",
-    "src/repro/faults/comm.py",
-    "src/repro/faults/wire.py",
     "src/repro/train/gnn_step.py",
     "src/repro/train/compression.py",
     "src/repro/train/optimizer.py",

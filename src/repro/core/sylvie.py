@@ -39,6 +39,14 @@ here once for every site:
   the compacted buffer are quantized, so Low-bit-Module FLOPs track the actual
   boundary set, not the padded worst case (paper §4.4 overhead budget).
 
+Each of the three halo primitives (``quantized_halo``, ``stale_halo``,
+``fresh_halo``) runs one Low-bit round trip per direction and takes two
+more inputs: a static ``fenced`` flag, which lands the exchange through
+``backend.fence`` (the ``"overlap"`` schedule, DESIGN §14), and an optional
+fault block (:class:`HaloFaults`, data), which checksums the exchange and
+lets a condemned row fall back to its stale value (DESIGN §12). With neither,
+each traces the blocking program.
+
 The *Bounded Staleness Adaptor* (paper §3.3) is the
 ``repro.policy.builtin.BoundedStaleness`` policy; the trainer runs the policy
 loop (``train/trainer.py``).
@@ -47,6 +55,7 @@ from __future__ import annotations
 
 import dataclasses
 from functools import partial
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -57,13 +66,15 @@ from ..policy.base import SiteDecision
 from . import quantization as qlib
 from .exchange import (PlanArrays, exchange_quantized_halo,
                        gather_boundary, scatter_boundary_grad)
+from .wire import checked_exchange
 
 Mode = str  # "vanilla" | "sync" | "async"
 
 # Exchange schedules: "blocking" consumes each halo exchange where it is
-# produced; "overlap" (dist/overlap.py) issues the quantized send early and
-# lands it through a backend fence so the collective can run under the
-# layer's local aggregation. Bit-exact under sync/fresh configs.
+# produced; "overlap" lands it through ``backend.fence`` (the primitives'
+# ``fenced`` flag) so the collective stays a standalone op the scheduler can
+# run under the layer's local aggregation. The fence is the identity on
+# values, so the two schedules compute the same bits.
 SCHEDULES = ("blocking", "overlap")
 
 
@@ -92,48 +103,101 @@ class SylvieConfig:
         return dataclasses.replace(self, **kw)
 
 
-def _q_roundtrip(buf, key, bits, stochastic, scale_dtype, backend, plan,
-                 reverse=False, impl="auto"):
-    """quantize -> exchange -> dequantize (one direction of the Low-bit Module).
-    ``reverse`` flips the ring direction for compact plans (backward comm)."""
+class HaloFaults(NamedTuple):
+    """A fault-armed site's fault block, riding as data: ``masks`` is the
+    site's :class:`~repro.faults.plan.SiteFaults` and ``fallback`` what a
+    condemned received row (dropped, or failing its checksum) keeps instead —
+    the previous halo cache for ``quantized_halo`` / ``fresh_halo``, the
+    previous ``grad_in`` for ``stale_halo``. Both are zero outside their
+    buffer's live rows (caches start zero and are only ever written by these
+    masked blends), so one blend is all a condemned row needs."""
+
+    fallback: jax.Array
+    masks: Any
+
+
+def _roundtrip(buf, key, bits, stochastic, scale_dtype, backend, plan, impl,
+               fenced, masks=None, reverse=False):
+    """quantize -> exchange -> dequantize: one direction of the Low-bit
+    Module. -> (values, ok).
+
+    ``reverse`` is the backward communication (compact plans run their rings
+    the other way). ``masks`` (a site's ``SiteFaults``) checksums the
+    exchange and injects that direction's faults; ``ok`` marks the rows the
+    receiver may use, ``None`` on the clean path. ``fenced`` lands the
+    exchange through ``backend.fence`` before dequantizing (the ``overlap``
+    schedule): identity on the values, a scheduling barrier in the program."""
     qt = qlib.quantize(buf, bits, key, stochastic, scale_dtype, impl=impl)
-    qr = exchange_quantized_halo(qt, plan, backend, reverse=reverse)
-    return qlib.dequantize(qr, impl=impl)
+    if masks is None:
+        qr, ok = exchange_quantized_halo(qt, plan, backend,
+                                         reverse=reverse), None
+    else:
+        corrupt, drop = ((masks.corrupt_bwd, masks.drop_bwd) if reverse
+                         else (masks.corrupt_fwd, masks.drop_fwd))
+        qr, ok = checked_exchange(qt, plan, backend, corrupt, drop,
+                                  reverse=reverse)
+    if fenced:
+        qr = backend.fence(qr)
+    return qlib.dequantize(qr, impl=impl), ok
+
+
+def _blend(x, mask, ok, fallback):
+    """Rows of ``x`` where ``mask`` holds and the checked exchange accepted
+    the row (``ok``; ``None`` accepts every row), ``fallback`` elsewhere."""
+    if ok is not None:
+        mask = ok & mask
+    return jnp.where(mask[..., None], x, fallback)
+
+
+def _masks(faults):
+    return None if faults is None else faults.masks
+
+
+def _fallback(faults):
+    return 0 if faults is None else faults.fallback
 
 
 # ---------------------------------------------------------------------------
 # Sylvie-S: synchronous quantized exchange with quantized backward communication
 # ---------------------------------------------------------------------------
-@partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
-def quantized_halo(h, plan: PlanArrays, fwd_key, bwd_key,
+@partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10, 11))
+def quantized_halo(h, faults, plan: PlanArrays, fwd_key, bwd_key,
                    fwd_bits: int, bwd_bits: int, stochastic: bool,
-                   scale_dtype, backend, impl):
+                   scale_dtype, backend, impl, fenced: bool):
     """(P, n_local, d) -> (P, halo_rows, d) dequantized halo features.
 
     ``fwd_bits`` quantizes the forward feature exchange, ``bwd_bits`` the
-    backward gradient communication — per-site, per-direction decisions."""
+    backward gradient communication — per-site, per-direction decisions.
+    ``faults`` (a :class:`HaloFaults` or ``None``) keeps the previous cache
+    row wherever the forward wire failed; a lost returned-gradient row
+    contributes zero, which is what a synchronous step's drained gradient
+    cache holds."""
     buf = gather_boundary(h, plan)
-    out = _q_roundtrip(buf, fwd_key, fwd_bits, stochastic, scale_dtype,
-                       backend, plan, impl=impl)
-    return jnp.where(plan.recv_mask[..., None], out, 0)
+    out, ok = _roundtrip(buf, fwd_key, fwd_bits, stochastic, scale_dtype,
+                         backend, plan, impl, fenced, _masks(faults))
+    return _blend(out, plan.recv_mask, ok, _fallback(faults))
 
 
-def _qh_fwd(h, plan, fwd_key, bwd_key, fwd_bits, bwd_bits, stochastic,
-            scale_dtype, backend, impl):
-    out = quantized_halo(h, plan, fwd_key, bwd_key,
-                         fwd_bits, bwd_bits, stochastic, scale_dtype, backend,
-                         impl)
-    return out, (plan, bwd_key)
+def _qh_fwd(h, faults, plan, fwd_key, bwd_key, fwd_bits, bwd_bits,
+            stochastic, scale_dtype, backend, impl, fenced):
+    out = quantized_halo(h, faults, plan, fwd_key, bwd_key, fwd_bits,
+                         bwd_bits, stochastic, scale_dtype, backend, impl,
+                         fenced)
+    return out, (plan, bwd_key, _masks(faults))
 
 
-def _qh_bwd(fwd_bits, bwd_bits, stochastic, scale_dtype, backend, impl, res,
-            g):
-    plan, bwd_key = res
+def _qh_bwd(fwd_bits, bwd_bits, stochastic, scale_dtype, backend, impl,
+            fenced, res, g):
+    plan, bwd_key, masks = res
     g = jnp.where(plan.recv_mask[..., None], g, 0)
-    back = _q_roundtrip(g, bwd_key, bwd_bits, stochastic, scale_dtype, backend,
-                        plan, reverse=True, impl=impl)
+    back, ok = _roundtrip(g, bwd_key, bwd_bits, stochastic, scale_dtype,
+                          backend, plan, impl, fenced, masks, reverse=True)
+    if ok is not None:
+        # scatter_boundary_grad masks the send rows itself; only a checked
+        # exchange has rows to condemn
+        back = _blend(back, plan.send_mask, ok, 0)
     grad_h = scatter_boundary_grad(back, plan)
-    return (grad_h, None, None, None)
+    return (grad_h, None, None, None, None)
 
 
 quantized_halo.defvjp(_qh_fwd, _qh_bwd)
@@ -142,45 +206,51 @@ quantized_halo.defvjp(_qh_fwd, _qh_bwd)
 # ---------------------------------------------------------------------------
 # Sylvie-A: stale halo consumption + fresh exchange emission
 # ---------------------------------------------------------------------------
-def fresh_halo(h, plan: PlanArrays, key, fwd_bits, stochastic, scale_dtype,
-               backend, impl="auto"):
+def fresh_halo(h, faults, plan: PlanArrays, key, fwd_bits, stochastic,
+               scale_dtype, backend, impl, fenced):
     """The concurrent forward exchange: quantize this step's boundary features and
     deliver them as *next* step's cache. Detached — no gradient flows (staleness
-    is handled by the grad_in path)."""
+    is handled by the grad_in path). Under ``faults`` a condemned row leaves
+    the old cache row in place, one step staler."""
     buf = gather_boundary(jax.lax.stop_gradient(h), plan)
-    out = _q_roundtrip(buf, key, fwd_bits, stochastic, scale_dtype, backend,
-                       plan, impl=impl)
-    return jnp.where(plan.recv_mask[..., None], out, 0)
+    out, ok = _roundtrip(buf, key, fwd_bits, stochastic, scale_dtype, backend,
+                         plan, impl, fenced, _masks(faults))
+    return _blend(out, plan.recv_mask, ok,
+                  0 if faults is None else jax.lax.stop_gradient(faults.fallback))
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10))
-def stale_halo(h, feat_cache, grad_in, gslot, plan: PlanArrays, bwd_key,
-               bwd_bits: int, stochastic: bool, scale_dtype, backend, impl):
+@partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9, 10, 11, 12))
+def stale_halo(h, feat_cache, grad_in, gslot, faults, plan: PlanArrays,
+               bwd_key, bwd_bits: int, stochastic: bool, scale_dtype, backend,
+               impl, fenced: bool):
     """Consume the stale halo; wire the staleness dataflow into autodiff.
 
     * primal output  = ``feat_cache`` (previous step's dequantized halo features)
     * grad wrt ``h``     = ``grad_in`` scattered onto boundary nodes (previous
       step's incoming boundary gradients — Alg. 2 line 13, one step stale)
     * grad wrt ``gslot`` = this step's outgoing quantized gradient exchange at
-      ``bwd_bits`` (surfaces to the caller as the next step's ``grad_in``)
+      ``bwd_bits`` (surfaces to the caller as the next step's ``grad_in``);
+      under ``faults`` a condemned row keeps its ``grad_in`` row, one epoch
+      staler
     """
-    del h, grad_in, gslot, plan, bwd_key
+    del h, grad_in, gslot, faults, plan, bwd_key
     return feat_cache
 
 
-def _sh_fwd(h, feat_cache, grad_in, gslot, plan, bwd_key,
-            bwd_bits, stochastic, scale_dtype, backend, impl):
-    return feat_cache, (plan, grad_in, bwd_key)
+def _sh_fwd(h, feat_cache, grad_in, gslot, faults, plan, bwd_key,
+            bwd_bits, stochastic, scale_dtype, backend, impl, fenced):
+    return feat_cache, (plan, grad_in, bwd_key, faults)
 
 
-def _sh_bwd(bwd_bits, stochastic, scale_dtype, backend, impl, res, g):
-    plan, grad_in, bwd_key = res
+def _sh_bwd(bwd_bits, stochastic, scale_dtype, backend, impl, fenced, res, g):
+    plan, grad_in, bwd_key, faults = res
     g = jnp.where(plan.recv_mask[..., None], g, 0)
-    fresh_grad = _q_roundtrip(g, bwd_key, bwd_bits, stochastic, scale_dtype,
-                              backend, plan, reverse=True, impl=impl)
-    fresh_grad = jnp.where(plan.send_mask[..., None], fresh_grad, 0)
+    fresh_grad, ok = _roundtrip(g, bwd_key, bwd_bits, stochastic, scale_dtype,
+                                backend, plan, impl, fenced, _masks(faults),
+                                reverse=True)
+    fresh_grad = _blend(fresh_grad, plan.send_mask, ok, _fallback(faults))
     grad_h = scatter_boundary_grad(grad_in, plan)
-    return (grad_h, None, None, fresh_grad, None, None)
+    return (grad_h, None, None, fresh_grad, None, None, None)
 
 
 stale_halo.defvjp(_sh_fwd, _sh_bwd)
@@ -282,33 +352,15 @@ class SylvieComm:
         kb = jax.random.fold_in(key, 2 * i + 1)
         self._record_stats(h)
         sf = self.fault_sites[i] if self.fault_sites is not None else None
-        if sf is not None:
-            # lazy import: repro.core.__init__ imports this module, and
-            # repro.faults.comm imports repro.core — a module-level import
-            # here would cycle.
-            from ..faults import comm as fcomm
-        # Fault-armed sites always run the blocking faulty primitives: the
-        # recovery blend needs the landed exchange immediately (DESIGN §14).
-        overlap = self.schedule == "overlap" and sf is None
-        if overlap:
-            # lazy import for the same reason as faults.comm above.
-            from ..dist import overlap as olap
+        # A fault-armed site never fences: its recovery blend needs the
+        # landed exchange immediately (DESIGN §14).
+        fenced = self.schedule == "overlap" and sf is None
+        wire = (sd.stochastic, cfg.scale_dtype, self.backend, cfg.quant_impl,
+                fenced)
         if cfg.mode in ("vanilla", "sync"):
-            if sf is not None:
-                halo = fcomm.faulty_quantized_halo(
-                    h, self.feat_caches[i], sf, self.plan, kf, kb,
-                    sd.fwd_bits, sd.bwd_bits, sd.stochastic, cfg.scale_dtype,
-                    self.backend, cfg.quant_impl)
-            elif overlap:
-                halo = olap.overlap_quantized_halo(
-                    h, self.plan, kf, kb, sd.fwd_bits, sd.bwd_bits,
-                    sd.stochastic, cfg.scale_dtype, self.backend,
-                    cfg.quant_impl)
-            else:
-                halo = quantized_halo(h, self.plan, kf, kb, sd.fwd_bits,
-                                      sd.bwd_bits, sd.stochastic,
-                                      cfg.scale_dtype, self.backend,
-                                      cfg.quant_impl)
+            faults = None if sf is None else HaloFaults(self.feat_caches[i], sf)
+            halo = quantized_halo(h, faults, self.plan, kf, kb, sd.fwd_bits,
+                                  sd.bwd_bits, *wire)
             bns = self._bns_mask(jax.random.fold_in(key, 999),
                                  sd.boundary_sample_p)
             if bns is not None:
@@ -326,32 +378,13 @@ class SylvieComm:
             # as an operand, and so as a live argument of the step.)
             obs.count("halo.bwd_pruned")
             halo = self.feat_caches[i]
-        elif sf is not None:
-            halo = fcomm.faulty_stale_halo(
-                h, self.feat_caches[i], self.grad_ins[i], self.gslots[i], sf,
-                self.plan, kb, sd.bwd_bits, sd.stochastic, cfg.scale_dtype,
-                self.backend, cfg.quant_impl)
-        elif overlap:
-            halo = olap.overlap_stale_halo(
-                h, self.feat_caches[i], self.grad_ins[i], self.gslots[i],
-                self.plan, kb, sd.bwd_bits, sd.stochastic, cfg.scale_dtype,
-                self.backend, cfg.quant_impl)
         else:
+            faults = None if sf is None else HaloFaults(self.grad_ins[i], sf)
             halo = stale_halo(h, self.feat_caches[i], self.grad_ins[i],
-                              self.gslots[i], self.plan, kb, sd.bwd_bits,
-                              sd.stochastic, cfg.scale_dtype, self.backend,
-                              cfg.quant_impl)
-        if sf is not None:
-            fresh = fcomm.faulty_fresh_halo(
-                h, self.feat_caches[i], sf, self.plan, kf, sd.fwd_bits,
-                sd.stochastic, cfg.scale_dtype, self.backend, cfg.quant_impl)
-        elif overlap:
-            fresh = olap.overlap_fresh_halo(
-                h, self.plan, kf, sd.fwd_bits, sd.stochastic,
-                cfg.scale_dtype, self.backend, cfg.quant_impl)
-        else:
-            fresh = fresh_halo(h, self.plan, kf, sd.fwd_bits, sd.stochastic,
-                               cfg.scale_dtype, self.backend, cfg.quant_impl)
+                              self.gslots[i], faults, self.plan, kb,
+                              sd.bwd_bits, *wire)
+        faults = None if sf is None else HaloFaults(self.feat_caches[i], sf)
+        fresh = fresh_halo(h, faults, self.plan, kf, sd.fwd_bits, *wire)
         self.new_feat_caches.append(fresh)
         return halo
 

@@ -4,9 +4,9 @@ The quantized wire payload gets a per-row integrity word: the sender computes
 an int32 byte-sum checksum over each row of the (packed or passthrough)
 payload *before* any injected corruption, ships it through the same exchange
 as an int32 sidecar, and the receiver recomputes it over what actually
-arrived. A mismatched row is *never dequantized into the model* — the caller
-treats it exactly like a dropped row and falls back to its cached halo
-(``faults/comm.py``).
+arrived. A mismatched row is *never dequantized into the model* — the halo
+primitives of ``core/sylvie.py`` treat it exactly like a dropped row and fall
+back to the site's cached halo.
 
 Injected corruption is a single XOR of bit 0 of byte 0 of the row — the
 smallest possible wire upset, and one a byte-sum checksum detects with
@@ -24,8 +24,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..core.exchange import PlanArrays, exchange_halo, exchange_quantized_halo
-from ..core.quantization import QuantizedTensor
+from .exchange import PlanArrays, exchange_halo, exchange_quantized_halo
+from .quantization import QuantizedTensor
 
 
 def _byte_view(data: jax.Array) -> jax.Array:

@@ -69,7 +69,8 @@ class HaloBackend(Protocol):
         line 16); identity in the simulated stack.
       * ``fence(tree)``              — land an in-flight exchange: identity on
         the data, a scheduling barrier in the lowered program (the overlap
-        schedule's in-order consumption point, ``dist/overlap.py``).
+        schedule's in-order consumption point: the ``fenced`` flag of the
+        halo primitives in ``core/sylvie.py``).
       * ``axis_index()``             — traced flat partition index, or ``None``
         when the whole stack is present (simulated).
 

@@ -1,14 +1,13 @@
 """repro.faults — seeded fault injection and staleness-as-recovery.
 
 Scheduling (`plan`) is host-side and deterministic in (seed, epoch);
-injection (`wire`, `comm`) is traced data so chaos adds zero executables;
+injection is traced data (the checked exchange of ``core/wire.py``, driven by
+the halo primitives of ``core/sylvie.py``) so chaos adds zero executables;
 `backend.FaultyBackend` binds a plan to any HaloBackend.
 """
 from .backend import FaultyBackend
-from .comm import faulty_fresh_halo, faulty_quantized_halo, faulty_stale_halo
 from .plan import (BWD, FWD, FaultCtl, FaultEvents, FaultPlan, RowGeometry,
                    SiteFaults)
-from .wire import checked_exchange, flip_rows, row_checksum
 
 __all__ = [
     "BWD",
@@ -19,10 +18,4 @@ __all__ = [
     "FaultyBackend",
     "RowGeometry",
     "SiteFaults",
-    "checked_exchange",
-    "faulty_fresh_halo",
-    "faulty_quantized_halo",
-    "faulty_stale_halo",
-    "flip_rows",
-    "row_checksum",
 ]

@@ -3,13 +3,13 @@
 The wrapper is deliberately *transparent on the wire*: every protocol method
 delegates to the wrapped backend unchanged. Fault injection does not happen
 here — the stacked/sharded collectives are all-or-nothing, so per-row drops
-and bit-flips are applied as traced data inside ``faults/comm.py`` (masks in
-``GNNTrainState.faults``), never by mutating the collective itself. What the
-wrapper *does* do is bind a :class:`~repro.faults.plan.FaultPlan` to a
-runtime: ``GNNTrainer`` discovers the plan on its ``Runtime``'s backend and
-arms the per-epoch schedule, so a single constructor argument
-(``Runtime(FaultyBackend(base, plan))``) turns any existing launch path into
-a chaos run.
+and bit-flips are applied as traced data by the halo primitives of
+``core/sylvie.py`` (masks in ``GNNTrainState.faults``), never by mutating
+the collective itself. What the wrapper *does* do is bind a
+:class:`~repro.faults.plan.FaultPlan` to a runtime: ``GNNTrainer`` discovers
+the plan on its ``Runtime``'s backend and arms the per-epoch schedule, so a
+single constructor argument (``Runtime(FaultyBackend(base, plan))``) turns
+any existing launch path into a chaos run.
 
 Frozen and hashable (it keys jit caches and rides custom_vjp nondiff
 argnums, exactly like the backends it wraps), and satisfies the runtime-

@@ -18,7 +18,7 @@ The chaos contract has two halves with very different trace disciplines:
 
 Fault taxonomy (DESIGN.md §12): ``drop`` (message lost → receiver reuses its
 stale cached halo), ``corrupt`` (payload bit-flipped on the wire → detected by
-the per-row checksum in ``faults/wire.py`` and handled exactly like a drop),
+the per-row checksum in ``core/wire.py`` and handled exactly like a drop),
 ``delay`` (straggler: delivered, but stalls the epoch's critical path —
 modeled, see :meth:`FaultEvents.stall_s`), and ``preempt`` (a whole partition
 down for the epoch: every message to/from it folds into ``drop``).

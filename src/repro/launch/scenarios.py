@@ -34,8 +34,7 @@ from ..dist.runtime import Runtime
 from ..faults import FaultPlan
 from ..models.gnn.models import PAPER_ARCHS as ARCHS
 from ..train.trainer import GNNTrainer
-from .cells import _gnn_model_flops
-from .mesh import ICI_BW, PEAK_FLOPS_BF16
+from .mesh import ICI_BW
 
 
 def parse_policy(spec: str):
@@ -115,8 +114,7 @@ class Scenario:
     # flat declarative record.
     fault: Optional[str] = None
     # exchange schedule for every cell ("blocking" | "overlap"). A scalar,
-    # not an axis: cell ids stay stable, and every report carries the DESIGN
-    # §8/§14 exposed/overlapped comm-time split either way.
+    # not an axis: cell ids stay stable.
     schedule: str = "blocking"
 
     def cells(self) -> tuple[Cell, ...]:
@@ -173,10 +171,11 @@ def default_out_dir() -> Path:
     return Path(__file__).resolve().parents[3] / "artifacts" / "scenarios"
 
 
-# Cell reports are versioned: v2 = v1 + {schema_version, obs, trace_path}.
+# Cell reports are versioned: v2 = v1 + {schema_version, obs, trace_path};
+# v3 = v2 - {modeled_tpu_comm_exposed_s, modeled_tpu_comm_overlapped_s}.
 # tests/test_scenarios.py pins the exact key set so keys cannot silently
 # drop (or silently appear untested).
-REPORT_SCHEMA_VERSION = 2
+REPORT_SCHEMA_VERSION = 3
 
 REPORT_KEYS = frozenset({
     "schema_version", "scenario", "cell", "arch", "dataset", "policy",
@@ -184,8 +183,7 @@ REPORT_KEYS = frozenset({
     "plan_cache_hit", "final_loss", "val_acc", "test_acc",
     "comm_payload_bytes_per_epoch", "comm_ec_bytes_per_epoch",
     "wire_payload_bytes_per_epoch", "wire_ec_bytes_per_epoch",
-    "modeled_tpu_comm_s", "schedule", "modeled_tpu_comm_exposed_s",
-    "modeled_tpu_comm_overlapped_s", "bits_per_site", "seconds", "fault",
+    "modeled_tpu_comm_s", "schedule", "bits_per_site", "seconds", "fault",
     "faults_injected", "halos_reused", "forced_syncs", "stall_s",
     "obs", "trace_path",
 })
@@ -239,15 +237,6 @@ def run_cell(scn: Scenario, cell: Cell, *,
         seconds = obs.clock() - t0
         pb, eb = tr.comm_bytes_per_epoch()
         wb, web = tr.wire_bytes_per_epoch()
-        # DESIGN §8/§14 comm-time split: per-partition analytic FLOPs bound
-        # each site's overlappable window; blocking exposes every comm second
-        # (exposed + overlapped == modeled_tpu_comm_s in both schedules).
-        n_nodes = int(pg.part_of.shape[0])
-        n_edges = int(pg.edge_mask.sum())
-        flops_per_part = _gnn_model_flops(cell.arch, model, n_nodes, n_edges,
-                                          pg.x.shape[-1], True) / scn.parts
-        exposed_s, overlapped_s = tr.modeled_comm_split(
-            flops_per_part, PEAK_FLOPS_BF16, ICI_BW)
         val_acc = float(tr.evaluate("val"))
         test_acc = float(tr.evaluate("test"))
     finally:
@@ -281,8 +270,6 @@ def run_cell(scn: Scenario, cell: Cell, *,
         "wire_ec_bytes_per_epoch": float(web),
         "modeled_tpu_comm_s": float((pb + eb) / scn.parts / ICI_BW),
         "schedule": scn.schedule,
-        "modeled_tpu_comm_exposed_s": float(exposed_s),
-        "modeled_tpu_comm_overlapped_s": float(overlapped_s),
         "bits_per_site": [list(b) for b in tr.history[-1].bits_per_site],
         "seconds": seconds,
         # chaos accounting (zeros when scn.fault is None); the invariant

@@ -217,10 +217,10 @@ def main() -> None:
     ap.add_argument("--bits", type=int, default=1)
     ap.add_argument("--schedule", default=None,
                     choices=["blocking", "overlap"],
-                    help="halo-exchange schedule: blocking, or the fenced "
-                         "issue/land overlap pipeline (dist/overlap.py; "
-                         "bit-exact under sync). With --scenario, overrides "
-                         "the scenario's schedule for every cell")
+                    help="halo-exchange schedule: blocking, or overlap "
+                         "(each exchange landed through a fence; bit-exact "
+                         "under sync). With --scenario, overrides the "
+                         "scenario's schedule for every cell")
     ap.add_argument("--policy", default="uniform",
                     choices=["uniform", "warmup", "bounded_staleness",
                              "adaqp"],

@@ -116,8 +116,9 @@ class EpochDecision:
     * ``ef_bits`` — EF21-compressed weight-gradient all-reduce bit-width
       (``None`` = full-precision psum, the paper's setting).
     * ``schedule`` — ``"blocking"`` (each halo consumed as it is produced) or
-      ``"overlap"`` (issue/land double buffering, ``dist/overlap.py``). Part
-      of :meth:`step_key`: the two schedules trace different programs.
+      ``"overlap"`` (each exchange landed through ``backend.fence``, the
+      ``fenced`` flag of the halo primitives in ``core/sylvie.py``). Part of
+      :meth:`step_key`: the two schedules trace different programs.
 
     Example — Sylvie-S at 1 bit on a 2-site model::
 

@@ -70,8 +70,9 @@ class ServeConfig:
     allowed but makes deltas unbiased rather than exact. ``max_staleness`` is
     the number of consecutive delta refreshes served before the next refresh
     is forced to a full sweep. ``schedule`` picks the exchange schedule of the
-    sweep executable (``"overlap"`` = fenced issue/land, ``dist/overlap.py``;
-    bit-exact to blocking — the serving sweep is always synchronous/fresh)."""
+    sweep executable (``"overlap"`` lands each exchange through
+    ``backend.fence``; bit-exact to blocking — the serving sweep is always
+    synchronous/fresh)."""
 
     bits: int = 1
     stochastic: bool = False

@@ -22,11 +22,11 @@ recompile budget. Omitting the decision falls back to the one global
 ``SylvieConfig`` choice (the Uniform degenerate case).
 
 The decision (or config) also picks the exchange *schedule*: ``"blocking"``
-consumes each halo where it is produced; ``"overlap"`` routes the same sites
-through the issue/land double buffering of ``dist/overlap.py`` (bit-exact
-under sync, the DESIGN §14 staleness contract under async). The schedule is
-part of ``EpochDecision.step_key()``, so each schedule traces its own
-executables within the same per-decision budget.
+consumes each halo where it is produced; ``"overlap"`` lands the same
+sites' exchanges through ``backend.fence`` (the ``fenced`` flag of the halo
+primitives in ``core/sylvie.py``; bit-exact to blocking, DESIGN §14). The
+schedule is part of ``EpochDecision.step_key()``, so each schedule traces its
+own executables within the same per-decision budget.
 
 The steps also *emit telemetry for the policy loop*: ``state.site_stats`` is a
 ``(n_sites, 2)`` array of ``[sum of squared boundary-row ranges, live row

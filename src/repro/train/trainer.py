@@ -300,25 +300,6 @@ class GNNTrainer:
         the layout-efficiency number the compact plan optimizes."""
         return self._bytes_per_epoch(wire_bytes, decision)
 
-    def modeled_comm_split(self, flops_per_part: float, peak_flops: float,
-                           ici_bw: float,
-                           decision: Optional[EpochDecision] = None
-                           ) -> tuple[float, float]:
-        """DESIGN §8/§14: modeled ``(exposed_s, overlapped_s)`` comm split per
-        epoch under this trainer's schedule. ``flops_per_part`` is the model's
-        analytic per-partition FLOPs (``launch.cells._gnn_model_flops`` /
-        n_parts); each site's overlappable compute window is its uniform
-        share of it. Blocking exposes everything; their sum is always the
-        ``modeled_tpu_comm_s`` total."""
-        from ..dist import overlap as olap
-        if decision is None:
-            decision = self._last_decision or self._decide()
-        comm = olap.site_comm_seconds(self.block.plan, self.site_dims,
-                                      decision, ici_bw, self.cfg.scale_dtype)
-        per_site = flops_per_part / peak_flops / max(self.n_sites, 1)
-        return olap.split_comm_time(comm, (per_site,) * self.n_sites,
-                                    decision.schedule)
-
     def _epoch_key(self):
         return jax.random.fold_in(self.key, self.epoch)
 

@@ -326,11 +326,11 @@ def test_overlap_census_fires_without_fence(monkeypatch):
     if len(jax.devices()) < 4:
         pytest.skip("needs 4 devices (XLA_FLAGS="
                     "--xla_force_host_platform_device_count=4)")
-    from repro.dist import overlap as olap
+    from repro.dist.backend import ShardMapBackend
 
     # strip the fence: values are unchanged (identity), but the land can fold
     # back into the issue — exactly what RC209's barrier census must catch
-    monkeypatch.setattr(olap, "fence", lambda backend, tree: tree)
+    monkeypatch.setattr(ShardMapBackend, "fence", lambda self, tree: tree)
     findings, _ = contracts.contract_overlap_census()
     assert any(f.code == "RC209" for f in findings)
 

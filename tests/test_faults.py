@@ -39,9 +39,9 @@ from repro import datasets
 from repro.core.exchange import PlanArrays, exchange_quantized_halo, gather_boundary
 from repro.core.quantization import quantize
 from repro.core.sylvie import SylvieConfig
+from repro.core.wire import checked_exchange, flip_rows, row_checksum
 from repro.dist.backend import SimulatedBackend
-from repro.faults import (BWD, FWD, FaultCtl, FaultPlan, RowGeometry,
-                          checked_exchange, flip_rows, row_checksum)
+from repro.faults import BWD, FWD, FaultCtl, FaultPlan, RowGeometry
 from repro.graph import formats, partition, synthetic
 from repro.models.gnn.models import GCN, PAPER_ARCHS
 from repro.policy import BoundedStaleness, Telemetry, Uniform

@@ -182,8 +182,8 @@ def test_quantized_backward_scatter_compact():
     x = jnp.asarray(pg.x)
 
     def f(h):
-        halo = quantized_halo(h, plan, KEY, KEY, 32, 32, False, jnp.bfloat16,
-                              None, "jnp")
+        halo = quantized_halo(h, None, plan, KEY, KEY, 32, 32, False,
+                              jnp.bfloat16, None, "jnp", False)
         return (halo ** 2).sum() / 2
 
     grad = jax.grad(f)(x)

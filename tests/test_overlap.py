@@ -21,7 +21,6 @@ import pytest
 
 import repro.api as repro
 from repro.core.sylvie import SCHEDULES, SylvieConfig
-from repro.dist import overlap as olap
 from repro.graph import formats, partition, synthetic
 from repro.models.gnn.models import GCN
 from repro.policy import BoundedStaleness
@@ -129,36 +128,13 @@ def test_loss_trajectory_parity_dense_vs_compact_under_overlap():
 
 
 # ---------------------------------------------------------------------------
-# schedule knob plumbing + comm-split model
+# schedule knob plumbing
 # ---------------------------------------------------------------------------
 def test_unknown_schedule_rejected():
     pg = _pg("compact", n=200)
     with pytest.raises(ValueError, match="unknown schedule"):
         _train(pg, "eager")
-    with pytest.raises(ValueError, match="unknown schedule"):
-        olap.split_comm_time((1.0,), (1.0,), "eager")
     assert "blocking" in SCHEDULES and "overlap" in SCHEDULES
-
-
-def test_modeled_comm_split():
-    """Blocking exposes every comm second; overlap hides up to each site's
-    compute window; the split always sums to the blocking total."""
-    pg = _pg("compact", n=400)
-    tr = _train(pg, "overlap", epochs=1)
-    flops = 1e9
-    exp_b, hid_b = _train(pg, "blocking", epochs=1).modeled_comm_split(
-        flops, 197e12, 50e9)
-    exp_o, hid_o = tr.modeled_comm_split(flops, 197e12, 50e9)
-    assert hid_b == 0.0 and exp_b > 0
-    assert hid_o > 0.0
-    np.testing.assert_allclose(exp_o + hid_o, exp_b, rtol=1e-12)
-    # pure-model invariants
-    comm, compute = (3.0, 1.0, 0.5), (1.0, 2.0, 0.1)
-    exp, hid = olap.split_comm_time(comm, compute, "overlap")
-    assert hid == sum(min(c, w) for c, w in zip(comm, compute))
-    assert exp + hid == sum(comm)
-    assert (olap.modeled_step_seconds(comm, compute, "overlap")
-            <= olap.modeled_step_seconds(comm, compute, "blocking"))
 
 
 def test_fence_is_value_transparent():

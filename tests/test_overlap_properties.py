@@ -1,21 +1,19 @@
-"""Property-based invariants of the quantized halo exchange + overlap model.
+"""Property-based invariants of the quantized halo exchange.
 
 Needs the ``hypothesis`` dev extra (CI installs it; skipped otherwise, like
-``test_graph.py``). Three families, each over randomly drawn skewed
+``test_graph.py``). Two families, each over randomly drawn skewed
 partitions — so the ring buckets are ragged and every example exercises a
 different static bucket-size tuple:
 
 * quantize -> exchange -> dequantize commutes with exchange -> dequantize
   across the whole low-bit lattice {1, 2, 4, 8} (the exchange permutes whole
   rows together with their per-row scale/zero, so dequantized values are
-  *bit-identical* either way — the property the overlap issue/land split
+  *bit-identical* either way — the property the fenced (overlap) schedule
   relies on to be value-transparent);
 * the compact ring exchange is an involution: ``reverse=True`` undoes
   ``reverse=False`` bit-exactly, for raw buffers and quantized payloads (the
-  backward-gradient path of ``dist/overlap.py`` depends on this inversion);
-* the DESIGN §14 comm-split model: exposed + overlapped always equals the
-  blocking total, the hidden share never exceeds either operand, and the
-  modeled overlap step is never slower than blocking.
+  backward gradient communication of ``core/sylvie.py`` depends on this
+  inversion).
 """
 import numpy as np
 import pytest
@@ -31,7 +29,6 @@ import jax  # noqa: E402
 from repro.core import quantization as qlib  # noqa: E402
 from repro.core.exchange import (PlanArrays, exchange_halo,  # noqa: E402
                                  exchange_quantized_halo)
-from repro.dist import overlap as olap  # noqa: E402
 from repro.dist.backend import SimulatedBackend  # noqa: E402
 from repro.graph import formats, partition, synthetic  # noqa: E402
 
@@ -89,23 +86,3 @@ def test_exchange_involution(n, parts, seed, bits):
         exchange_quantized_halo(qt, plan, BE), plan, BE, reverse=True)
     for a, b in zip(jax.tree.leaves(qt), jax.tree.leaves(qback)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-
-
-@settings(max_examples=50, deadline=None)
-@given(st.data())
-def test_comm_split_model_invariants(data):
-    """Pure-model properties of split_comm_time / modeled_step_seconds."""
-    n_sites = data.draw(st.integers(min_value=1, max_value=6))
-    secs = st.floats(min_value=0.0, max_value=10.0,
-                     allow_nan=False, allow_infinity=False)
-    comm = tuple(data.draw(secs) for _ in range(n_sites))
-    compute = tuple(data.draw(secs) for _ in range(n_sites))
-    exp_b, hid_b = olap.split_comm_time(comm, compute, "blocking")
-    exp_o, hid_o = olap.split_comm_time(comm, compute, "overlap")
-    assert hid_b == 0.0 and exp_b == pytest.approx(sum(comm))
-    assert exp_o + hid_o == pytest.approx(sum(comm))
-    assert hid_o <= min(sum(comm), sum(compute)) + 1e-12
-    assert (olap.modeled_step_seconds(comm, compute, "overlap")
-            <= olap.modeled_step_seconds(comm, compute, "blocking") + 1e-12)
-    with pytest.raises(ValueError):
-        olap.split_comm_time(comm, compute, "eager")

@@ -5,22 +5,21 @@ import pytest
 
 from repro.launch import scenarios as S
 
-# The v1 report keys, pinned independently of the source: v2 must stay a
-# strict superset (schema versioning means old consumers keep working).
+# The v1 report keys less the two modeled comm-split keys v3 dropped, pinned
+# independently of the source: the report must stay a strict superset.
 V1_REPORT_KEYS = {
     "scenario", "cell", "arch", "dataset", "policy", "policy_spec", "mode",
     "runtime", "n_parts", "epochs", "seed", "plan_cache_hit", "final_loss",
     "val_acc", "test_acc", "comm_payload_bytes_per_epoch",
     "comm_ec_bytes_per_epoch", "wire_payload_bytes_per_epoch",
     "wire_ec_bytes_per_epoch", "modeled_tpu_comm_s", "schedule",
-    "modeled_tpu_comm_exposed_s", "modeled_tpu_comm_overlapped_s",
     "bits_per_site", "seconds", "fault", "faults_injected", "halos_reused",
     "forced_syncs", "stall_s",
 }
 
 
 def test_report_schema_is_versioned_superset():
-    assert S.REPORT_SCHEMA_VERSION == 2
+    assert S.REPORT_SCHEMA_VERSION == 3
     assert V1_REPORT_KEYS < S.REPORT_KEYS
     assert S.REPORT_KEYS - V1_REPORT_KEYS == \
         {"schema_version", "obs", "trace_path"}

@@ -12,7 +12,10 @@ from repro.core import quantization as q
 from repro.core.exchange import (PlanArrays, exchange, gather_boundary,
                                  scatter_boundary_grad)
 from repro.core.staleness import HaloState, use_sync_step
-from repro.core.sylvie import SylvieComm, SylvieConfig, quantized_halo
+from repro.core.sylvie import (HaloFaults, SylvieComm, SylvieConfig,
+                               fresh_halo, quantized_halo, stale_halo)
+from repro.dist.backend import SimulatedBackend
+from repro.faults import SiteFaults
 from repro.graph import formats, partition, synthetic
 from repro.models.gnn import blocks as B
 from repro.models.gnn.models import GAT, GCN
@@ -97,8 +100,8 @@ def test_backward_scatter_adds_duplicate_sends():
     x = jnp.asarray(pg.x)
 
     def f(h):
-        halo = quantized_halo(h, plan, KEY, KEY, 32, 32, False, jnp.bfloat16,
-                              None, "jnp")
+        halo = quantized_halo(h, None, plan, KEY, KEY, 32, 32, False,
+                              jnp.bfloat16, None, "jnp", False)
         return (halo ** 2).sum() / 2
 
     g = jax.grad(f)(x)
@@ -111,6 +114,69 @@ def test_backward_scatter_adds_duplicate_sends():
             if sends[p, slot]:
                 expected[p, idx[p, slot]] += np.asarray(x)[p, idx[p, slot]]
     np.testing.assert_allclose(np.asarray(g), expected, rtol=1e-4, atol=1e-5)
+
+
+def _skewed_pg(layout):
+    g = synthetic.powerlaw(n_nodes=160, d_feat=8, avg_degree=8, seed=0)
+    ei = formats.add_self_loops(g.edge_index, g.n_nodes)
+    ew = formats.gcn_edge_weights(ei, g.n_nodes)
+    g = formats.Graph(g.n_nodes, ei, g.x, g.y, g.train_mask, g.val_mask,
+                      g.test_mask, n_classes=g.n_classes)
+    return partition.partition_graph(g, 4, method="skewed", edge_weight=ew,
+                                     layout=layout)
+
+
+def _halo_semantics(layout):
+    """``{semantic: (call(faults, fenced) -> (out, vjp_fn), its fault block
+    with all-false masks)}``, a cotangent key and the halo shape, on one
+    plan. The fallbacks are random on the live rows and zero elsewhere, as
+    the trainer's caches are."""
+    pg = _skewed_pg(layout)
+    plan = PlanArrays.from_plan(pg.plan)
+    be = SimulatedBackend()
+    k = jax.random.split(KEY, 5)
+    h = jnp.asarray(pg.x)
+    halo_shape = plan.recv_mask.shape + (h.shape[-1],)
+    cache = jax.random.normal(k[0], halo_shape) * plan.recv_mask[..., None]
+    grad_in = jax.random.normal(k[1], halo_shape) * plan.send_mask[..., None]
+    gslot = jnp.zeros(halo_shape)
+    wire = (True, jnp.bfloat16, be, "jnp")
+
+    def quantized(faults, fenced):
+        return jax.vjp(lambda x: quantized_halo(
+            x, faults, plan, k[2], k[3], 1, 1, *wire, fenced), h)
+
+    def stale(faults, fenced):
+        return jax.vjp(lambda x, gs: stale_halo(
+            x, cache, grad_in, gs, faults, plan, k[3], 1, *wire, fenced),
+            h, gslot)
+
+    def fresh(faults, fenced):
+        return jax.vjp(lambda x: fresh_halo(
+            x, faults, plan, k[2], 1, *wire, fenced), h)
+
+    clean = SiteFaults(*(jnp.zeros(plan.recv_mask.shape, bool),) * 4)
+    return {"quantized": (quantized, HaloFaults(cache, clean)),
+            "stale": (stale, HaloFaults(grad_in, clean)),
+            "fresh": (fresh, HaloFaults(cache, clean))}, k[4], halo_shape
+
+
+@pytest.mark.parametrize("layout", ["dense", "compact"])
+@pytest.mark.parametrize("variant", ["fenced", "faults_armed_clean"])
+@pytest.mark.parametrize("semantic", ["quantized", "stale", "fresh"])
+def test_halo_primitive_variants_bit_identical(semantic, variant, layout):
+    """The fence and an armed fault block with all-false masks change no
+    bit of a halo primitive: forward values and vjp cotangents equal the
+    plain (unfenced, fault-free) call's, on a dense and a compact plan."""
+    semantics, kg, halo_shape = _halo_semantics(layout)
+    call, faults = semantics[semantic]
+    out_ref, vjp_ref = call(None, False)
+    out, vjp = (call(None, True) if variant == "fenced"
+                else call(faults, False))
+    g = jax.random.normal(kg, halo_shape)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(out_ref))
+    for a, b in zip(jax.tree.leaves(vjp(g)), jax.tree.leaves(vjp_ref(g))):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 def test_async_one_step_staleness_dataflow():

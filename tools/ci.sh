@@ -8,10 +8,7 @@
 #                              checks in-process instead of skipping them)
 #   tools/ci.sh --bench-smoke  fast benchmark regression checks: bench_halo
 #                              fails if the compact layout's wire-byte
-#                              reduction regresses past 60%; bench_overlap
-#                              fails if the overlap schedule stops hiding comm
-#                              (modeled step must beat compute + comm) or
-#                              loses bit-exactness vs blocking; bench_serve fails
+#                              reduction regresses past 60%; bench_serve fails
 #                              if the quantized delta refresh ships more than
 #                              10% of the full 32-bit sweep bytes; bench_chaos
 #                              fails if the armed fault path's epoch overhead
@@ -103,7 +100,6 @@ case "${1:-}" in
   --bench-smoke)
     shift
     python -m benchmarks.bench_halo --smoke "$@"
-    python -m benchmarks.bench_overlap --smoke "$@"
     python -m benchmarks.bench_serve --smoke "$@"
     python -m benchmarks.bench_chaos --smoke "$@"
     exec python -m benchmarks.bench_store --smoke "$@"
